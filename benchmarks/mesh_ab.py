@@ -20,7 +20,8 @@ With ``--assert`` both arms must hold syncs/cycle == 1 in steady state
 and commit bit-identical tokens — the CI smoke for mesh-sharded serving.
 Emits a ``BENCH_9.json`` snapshot.
 
-Run directly (the module spawns the virtual devices itself):
+Run directly (under ``JAX_PLATFORMS=cpu`` the module spawns the virtual
+devices itself; on accelerators the mesh takes the host's devices):
 
     PYTHONPATH=src python -m benchmarks.mesh_ab [--assert] [--mesh 2x4]
 
@@ -31,21 +32,12 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 from typing import Dict
-
-# The mesh arm needs its devices to EXIST before jax initializes the CPU
-# backend: spawn virtual devices before any jax-importing import below
-# runs.  Respect a user-provided XLA_FLAGS (the CI job exports one).
-if "--xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8").strip()
 
 import numpy as np
 
 from repro.core import ChainRouter, ModelPool, Placement
+from repro.launch.mesh import request_cpu_devices
 
 CHAIN = ("bench-68m", "bench-1b", "bench-7b")
 
@@ -111,11 +103,10 @@ def main(max_new: int = 32, batch: int = 4, window: int = 4,
     import jax
     need = int(np.prod([int(x) for x in mesh.split("x")]))
     if jax.device_count() < need:
-        # XLA_FLAGS was preset without enough devices — report, don't die
-        print(f"mesh_ab,skip,need {need} devices have {jax.device_count()}"
-              " (export XLA_FLAGS=--xla_force_host_platform_device_count="
-              f"{need})")
-        return {}
+        raise RuntimeError(
+            f"mesh_ab needs {need} devices, have {jax.device_count()} "
+            "(on the CPU: JAX_PLATFORMS=cpu XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={need})")
 
     prompts = np.array(jax.random.randint(jax.random.PRNGKey(7),
                                           (batch, 12), 0, 127))
@@ -168,5 +159,6 @@ if __name__ == "__main__":
     ap.add_argument("--window", type=int, default=4)
     ap.add_argument("--out-json", default="BENCH_9.json")
     a = ap.parse_args()
+    request_cpu_devices(int(np.prod([int(x) for x in a.mesh.split("x")])))
     main(max_new=a.max_new, batch=a.batch, window=a.window, mesh=a.mesh,
          do_assert=a.do_assert, out_json=a.out_json)

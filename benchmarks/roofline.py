@@ -18,7 +18,10 @@ import os
 from typing import Dict, List
 
 from repro.configs import INPUT_SHAPES, get_config, effective_shape
-from repro.launch.mesh import (HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16)
+from repro.launch.mesh import chip_peaks
+
+# the dry-run's production mesh models a TPU v5e pod
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
 
 HERE = os.path.dirname(__file__)
 RESULTS = os.path.join(HERE, "dryrun_results")
@@ -44,9 +47,10 @@ def analyze_record(rec: Dict) -> Dict:
     hbm = rec.get("hbm_bytes_loop_aware", rec.get("bytes_accessed", 0.0))
     coll = rec.get("collective_bytes_loop_aware",
                    rec.get("collectives", {}).get("total", 0.0))
-    t_comp = flops / PEAK_FLOPS_BF16
-    t_mem = hbm / HBM_BW
-    t_coll = coll / ICI_BW_PER_LINK
+    peaks = chip_peaks(DRYRUN_DEVICE_KIND)
+    t_comp = flops / peaks.flops_bf16
+    t_mem = hbm / peaks.hbm_bw
+    t_coll = coll / peaks.ici_bw_per_link
     terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops_per_device(rec)

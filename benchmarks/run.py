@@ -3,6 +3,8 @@
     PYTHONPATH=src python -m benchmarks.run [--quick]
 
 Prints ``name,...,derived`` CSV per benchmark (see each module docstring).
+Everything runs in this one process, and a benchmark that fails ends the
+run with a non-zero exit.
 """
 from __future__ import annotations
 
@@ -35,9 +37,15 @@ def main() -> None:
                          "depth-4 sweep)")
     args = ap.parse_args()
 
+    from repro.launch.mesh import request_cpu_devices
+    if not args.skip_mesh:
+        # mesh_ab runs in this process: its 8 devices must exist before
+        # the first JAX computation below (virtual ones on the CPU)
+        request_cpu_devices(8)
+
     from . import (analytic_model, chain_selection, cycle_overhead,
-                   goodput_ab, roofline, routing_ab, serving_metrics,
-                   table2_speedup, tree_ab)
+                   goodput_ab, mesh_ab, roofline, routing_ab,
+                   serving_metrics, table2_speedup, tree_ab)
 
     t0 = time.time()
     print("# analytic_model (paper Eq. 2/3/4)")
@@ -45,10 +53,7 @@ def main() -> None:
 
     print("# roofline (deliverable g - from dry-run artifacts)")
     for mesh in ("single", "multi"):
-        try:
-            roofline.main(mesh=mesh)
-        except Exception as e:  # noqa: BLE001
-            print(f"roofline,{mesh},unavailable,{e}")
+        roofline.main(mesh=mesh)
 
     print("# chain_selection (paper Fig. 2)")
     chain_selection.main()
@@ -83,19 +88,9 @@ def main() -> None:
 
     if not args.skip_mesh:
         print("# mesh_ab (single-device vs mesh-sharded placement)")
-        # subprocess, not an import: the mesh arm must spawn its virtual
-        # devices via XLA_FLAGS BEFORE jax initializes a backend, and
-        # this process's jax is already live.  out_json="": the committed
-        # BENCH_9.json baseline is the canonical max_new=32 run
-        # (regenerated by the mesh-serving CI job).
-        import subprocess
-        import sys
-        rc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.mesh_ab",
-             "--max-new", "12" if args.quick else "24",
-             "--out-json", ""]).returncode
-        if rc:
-            print(f"mesh_ab,error,exit={rc}")
+        # out_json="": the committed BENCH_9.json baseline is the
+        # canonical max_new=32 run (regenerated by the mesh-serving CI job)
+        mesh_ab.main(max_new=12 if args.quick else 24, out_json="")
 
     if not args.skip_serving:
         print("# serving_metrics (paper SS5 metrics)")

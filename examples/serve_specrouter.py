@@ -23,31 +23,19 @@ baselines for the EAF speedup.
                             # already unmeetable (goodput over latency)
         [--mesh dxm]        # mesh-sharded serving: place the pool on a
                             # ("data","model") device mesh (target
-                            # tensor-parallel, drafts replicated); on a
-                            # CPU host virtual devices are spawned
-                            # automatically to fill the mesh
+                            # tensor-parallel, drafts replicated); under
+                            # JAX_PLATFORMS=cpu virtual devices are
+                            # spawned to fill the mesh
+
+The compile cache lives in $JAX_COMPILATION_CACHE_DIR when it is set, else
+in ``.jax_cache/`` at the repository root.
 """
 import argparse
 import math
-import os
-import sys
-
-# --mesh needs the devices to EXIST before jax initializes its backend:
-# spawn virtual CPU devices (the launch/dryrun.py recipe) before any
-# jax-importing import below runs.  Respect a user-provided XLA_FLAGS.
-if "--mesh" in sys.argv and "--xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    _spec = sys.argv[sys.argv.index("--mesh") + 1]
-    _n = 1
-    for _p in _spec.split("x"):
-        _n *= int(_p)
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               f" --xla_force_host_platform_device_count={_n}"
-                               ).strip()
-
-import numpy as np
 
 from repro.data import load_trace, make_bursty_workload, make_workload
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import request_cpu_devices
 from repro.serving import ServingEngine
 from repro.train.pool import build_trained_pool
 
@@ -144,9 +132,13 @@ def main():
                     help="place the pool on a ('data','model') device "
                          "mesh, e.g. 2x4: the target is tensor-parallel "
                          "over the model axis, drafts are replicated; "
-                         "virtual CPU devices are spawned to fill the "
-                         "mesh when needed")
+                         "under JAX_PLATFORMS=cpu virtual devices are "
+                         "spawned to fill the mesh")
     args = ap.parse_args()
+    # both must precede the first JAX computation
+    enable_compile_cache()
+    if args.mesh:
+        request_cpu_devices(math.prod(int(p) for p in args.mesh.split("x")))
     if args.workload == "trace" and not args.trace_file:
         ap.error("--workload trace requires --trace-file")
     if args.shed and args.ttft_slo is None:

@@ -27,7 +27,6 @@ import jax
 from . import harness
 from .findings import Finding
 
-FORBIDDEN_PRIM_SUBSTRINGS = ("callback", "infeed", "outfeed")
 
 _EXECUTOR_PATH = "src/repro/core/executor.py"
 _OPS_PATH = "src/repro/kernels/ops.py"
@@ -65,13 +64,21 @@ def _sub_jaxprs(v: Any) -> List[Any]:
     return subs
 
 
+def is_host_hop(eqn) -> bool:
+    """True when an equation hands control to the host.  Keyed on what the
+    equation does, not on its name: it carries an effect other than a
+    read/write of one of its own operands (debug print/callback,
+    io_callback, in/outfeed — kernel-body ref accesses carry an
+    ``input_index``), or it calls back into Python (``pure_callback``
+    carries no effect)."""
+    if callable(eqn.params.get("callback")):
+        return True
+    return any(not hasattr(e, "input_index") for e in eqn.effects)
+
+
 def forbidden_primitives(jaxpr) -> List[str]:
-    hits = []
-    for eqn in iter_all_eqns(jaxpr):
-        name = eqn.primitive.name
-        if any(s in name for s in FORBIDDEN_PRIM_SUBSTRINGS):
-            hits.append(name)
-    return hits
+    return [eqn.primitive.name for eqn in iter_all_eqns(jaxpr)
+            if is_host_hop(eqn)]
 
 
 def check_entry_point(name: str, fn: Callable, args: Sequence[Any],

@@ -217,14 +217,16 @@ def default_drives() -> List[Tuple[Callable, tuple, dict]]:
     logits_b = rng.standard_normal((Rr, V), dtype=np.float32)
     cand = rng.integers(0, V, size=(Rr,)).astype(np.int32)
 
+    # the capture never runs a kernel, so the interpret flag is inert
+    kw = {"interpret": True}
     return [
-        (_attn.masked_decode_attention_pallas, (q1, k, v, mask1), {}),
-        (_attn.masked_tree_attention_pallas, (qT, k, v, maskT), {}),
-        (_attn.paged_flash_decode_pallas, (qT, kp, vp, table, maskP), {}),
-        (_verify.verify_stats_pallas, (logits, cand), {}),
-        (_verify.topk_pallas, (logits, 4), {}),
-        (_dtv.softmax_stats, (logits,), {}),
-        (_dtv.dtv_pallas, (logits, logits_b), {}),
+        (_attn.masked_decode_attention_pallas, (q1, k, v, mask1), kw),
+        (_attn.masked_tree_attention_pallas, (qT, k, v, maskT), kw),
+        (_attn.paged_flash_decode_pallas, (qT, kp, vp, table, maskP), kw),
+        (_verify.verify_stats_pallas, (logits, cand), kw),
+        (_verify.topk_pallas, (logits, 4), kw),
+        (_dtv.softmax_stats, (logits,), kw),
+        (_dtv.dtv_pallas, (logits, logits_b), kw),
     ]
 
 

@@ -15,22 +15,31 @@ ARCH_ID = "llama-pool"
 
 
 def full_pool():
-    """Paper-scale configs (dry-run / documentation only on this host)."""
+    """Paper-scale configs at their published widths, as in each model's
+    Hugging Face ``config.json`` (all three have untied output heads)."""
     base = dict(arch_type="dense", rope_theta=10_000.0, dtype=jnp.bfloat16,
-                max_position=4096, source="[paper §5 Models]")
+                vocab_size=32000, tie_embeddings=False)
     return [
         ModelConfig(name="llama-68m", num_layers=2, d_model=768,
                     num_heads=12, num_kv_heads=12, d_ff=3072,
-                    vocab_size=32000, **base),
+                    rms_eps=1e-6, max_position=2048,
+                    source="[paper §5 Models; hf:JackFram/llama-68m "
+                           "config.json]", **base),
         ModelConfig(name="tinyllama-1.1b", num_layers=22, d_model=2048,
                     num_heads=32, num_kv_heads=4, d_ff=5632,
-                    vocab_size=32000, **base),
+                    rms_eps=1e-5, max_position=2048,
+                    source="[paper §5 Models; hf:TinyLlama/TinyLlama-1.1B-"
+                           "Chat-v1.0 config.json]", **base),
         ModelConfig(name="llama-2-7b", num_layers=32, d_model=4096,
                     num_heads=32, num_kv_heads=32, d_ff=11008,
-                    vocab_size=32000, **base),
+                    rms_eps=1e-5, max_position=4096,
+                    source="[paper §5 Models; hf:meta-llama/Llama-2-7b-hf "
+                           "config.json]", **base),
         ModelConfig(name="llama-2-13b", num_layers=40, d_model=5120,
                     num_heads=40, num_kv_heads=40, d_ff=13824,
-                    vocab_size=32000, **base),
+                    rms_eps=1e-5, max_position=4096,
+                    source="[hf:meta-llama/Llama-2-13b-hf config.json]",
+                    **base),
     ]
 
 

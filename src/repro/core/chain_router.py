@@ -107,6 +107,8 @@ class CycleReport:
     acc_mean: float               # mean committed over pre-cycle active slots
     groups: List[Tuple[Tuple[str, ...], int, int]] = \
         dataclasses.field(default_factory=list)
+    fused: bool = False           # every group ran as one device program
+    host_syncs: int = 0           # profiler host_sync count of this cycle
 
 
 class ChainRouter:
@@ -1281,6 +1283,8 @@ class RouterSession:
         ginfo: List[Tuple[Tuple[str, ...], int, int]] = []
         profiling = (not r.fused) or (r.profile_every > 0
                                       and self.steps % r.profile_every == 0)
+        all_fused = True
+        syncs0 = r.profiler.counters["host_sync"]
         t0 = _time.perf_counter()
         for key in order:
             gmask = groups[key] & self.active
@@ -1293,6 +1297,7 @@ class RouterSession:
             if r.fused and not profiling:
                 acc = self._run_fused_group(choice, gmask, slot_keys)
             if acc is None:          # profiling cycle or fused fallback
+                all_fused = False
                 acc = r._one_cycle(choice.chain, choice.window,
                                    self.session_id, self.seq,
                                    self.seq_len, gmask, tree=choice.tree,
@@ -1329,7 +1334,9 @@ class RouterSession:
         self.committed += int(survived.sum())
         lead = ginfo[0] if ginfo else ((), 0, 0)
         return CycleReport(n_acc, wall, lead[0], lead[1], acc_mean,
-                           groups=ginfo)
+                           groups=ginfo, fused=all_fused and bool(ginfo),
+                           host_syncs=int(r.profiler.counters["host_sync"]
+                                          - syncs0))
 
     def generated(self, slot: int) -> np.ndarray:
         """The slot's committed output tokens so far (prompt excluded)."""

@@ -201,7 +201,7 @@ class FusedSummary(NamedTuple):
 # ---------------------------------------------------------------------------
 # Fused-cycle device helpers (pure jnp, traced inside the fused program)
 # ---------------------------------------------------------------------------
-_BIG = jnp.int32(2 ** 30)     # OOB sentinel for mode="drop" scatters
+_BIG = np.int32(2 ** 30)      # OOB sentinel for mode="drop" scatters
 _NO_POOL = 2 ** 30            # free_top sentinel for contiguous states
 
 

@@ -58,10 +58,15 @@ def parse_mesh(spec: str, devices=None) -> Mesh:
     d, mm = int(m.group(1) or 1), int(m.group(2))
     devices = list(devices if devices is not None else jax.devices())
     if d * mm > len(devices):
+        platform = devices[0].platform if devices else "none"
+        hint = ("spawn virtual CPU devices with XLA_FLAGS="
+                "--xla_force_host_platform_device_count=N"
+                if platform == "cpu" else
+                f"this host has {len(devices)} {platform} device(s); "
+                "choose a mesh that fits them")
         raise ValueError(
             f"mesh {d}x{mm} needs {d * mm} devices, have {len(devices)} "
-            "(spawn virtual CPU devices with "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
+            f"({hint})")
     return Mesh(np.array(devices[:d * mm]).reshape(d, mm),
                 ("data", "model"))
 
@@ -208,7 +213,7 @@ class Placement:
         byte-identical unmeshed kernel path."""
         if self.mesh is None or self.mesh.size == 1:
             return contextlib.nullcontext()
-        return self.mesh
+        return jax.set_mesh(self.mesh)
 
     def reshard_between_levels(self) -> Optional[Callable[[Any], Any]]:
         """The fused-cycle level-boundary reshard: candidate tokens/probs

@@ -43,8 +43,13 @@ BLK_S = 512
 NEG = -1e30
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref,
-                 *, scale):
+def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref,
+                  *, scale):
+    """One KV block of the online softmax for one (row, kv-head) pair.
+
+    Refs (leading grid dims squeezed): q (R, D) with R = T·g query rows
+    (tree node major, GQA group member minor); k, v (BLK, D); mask (R, BLK)
+    int32; acc (R, D); m, l (R, 128) lane-replicated running max / sum."""
     s_idx = pl.program_id(2)
 
     @pl.when(s_idx == 0)
@@ -53,144 +58,105 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (BLK_S, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # (BLK_S, D)
-    msk = mask_ref[0]                                     # (BLK_S,)
+    q = q_ref[...].astype(jnp.float32) * scale            # (R, D)
+    k = k_ref[...].astype(jnp.float32)                    # (BLK, D)
+    v = v_ref[...].astype(jnp.float32)                    # (BLK, D)
+    scores = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    scores = jnp.where(mask_ref[...] != 0, scores, NEG)   # (R, BLK)
 
-    scores = q @ k.T                                      # (g, BLK_S)
-    scores = jnp.where(msk[None, :], scores, NEG)
-
-    m_old = m_ref[0, 0][:, :1]                            # (g, 1)
+    m_old = m_ref[...][:, :1]                             # (R, 1)
     m_new = jnp.maximum(m_old, jnp.max(scores, axis=-1, keepdims=True))
     p = jnp.where(scores > NEG * 0.5, jnp.exp(scores - m_new), 0.0)
     corr = jnp.where(m_old > NEG * 0.5, jnp.exp(m_old - m_new), 0.0)
 
-    l_ref[0, 0] = jnp.broadcast_to(
-        l_ref[0, 0][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-        l_ref[0, 0].shape)
-    acc_ref[0, 0] = acc_ref[0, 0] * corr + p @ v
-    m_ref[0, 0] = jnp.broadcast_to(m_new, m_ref[0, 0].shape)
+    l_new = l_ref[...][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
+def _head_major(q, mask, Hkv):
+    """(B, T, H, D) queries and (B, T, S) mask rows -> (B, Hkv, T·g, D)
+    query tiles and the matching (B, T·g, S) int32 mask rows."""
+    B, T, H, D = q.shape
+    g = H // Hkv
+    qh = q.reshape(B, T, Hkv, g, D).transpose(0, 2, 1, 3, 4)
+    mask_rows = jnp.repeat(mask.astype(jnp.int32), g, axis=1)
+    return qh.reshape(B, Hkv, T * g, D), mask_rows
+
+
+def _normalize(acc, l, B, T, H, dtype):
+    """(B, Hkv, T·g, D) accumulators -> (B, T, H, D) attention output."""
+    Hkv, D = acc.shape[1], acc.shape[-1]
+    l1 = l[..., :1]
+    out = jnp.where(l1 > 0, acc / jnp.maximum(l1, 1e-30), 0.0)
+    out = out.reshape(B, Hkv, T, H // Hkv, D).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, T, H, D).astype(dtype)
+
+
+def _acc_shapes(B, Hkv, R, D):
+    return [jax.ShapeDtypeStruct((B, Hkv, R, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, R, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, R, 128), jnp.float32)]
 
 
 def masked_decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray,
                                    v: jnp.ndarray, mask: jnp.ndarray,
-                                   scale: float | None = None,
-                                   interpret: bool = True) -> jnp.ndarray:
-    """q: (B, H, D); k, v: (B, S, Hkv, D); mask: (B, S).
+                                   *, interpret: bool,
+                                   scale: float | None = None
+                                   ) -> jnp.ndarray:
+    """q: (B, H, D); k, v: (B, S, Hkv, D); mask: (B, S) -> (B, H, D).
 
-    S must be a BLK_S multiple and D 128-aligned (ops.py pads)."""
-    B, H, D = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
-    g = H // Hkv
-    scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    qg = q.reshape(B, Hkv, g, D)
-    grid = (B, Hkv, S // BLK_S)
-
-    acc, m, l = pl.pallas_call(
-        functools.partial(_attn_kernel, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, BLK_S, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, BLK_S, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, BLK_S), lambda b, h, s: (b, s)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 128), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 128), lambda b, h, s: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, g, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, g, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, g, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qg, k, v, mask)
-
-    l1 = l[..., :1]
-    out = jnp.where(l1 > 0, acc / jnp.maximum(l1, 1e-30), 0.0)
-    return out.reshape(B, H, D).astype(q.dtype)
+    The T=1 case of ``masked_tree_attention_pallas``."""
+    out = masked_tree_attention_pallas(q[:, None], k, v, mask[:, None],
+                                       interpret=interpret, scale=scale)
+    return out[:, 0]
 
 
 # ---------------------------------------------------------------------------
 # Tree-block decode attention: T queries, per-query ancestor mask
 # ---------------------------------------------------------------------------
-def _tree_attn_kernel(q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref,
-                      *, scale):
-    s_idx = pl.program_id(2)
-
-    @pl.when(s_idx == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, :, 0].astype(jnp.float32) * scale        # (T, g, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)             # (BLK_S, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)             # (BLK_S, D)
-    msk = mask_ref[0]                                      # (T, BLK_S)
-    T, g, D = q.shape
-
-    scores = (q.reshape(T * g, D) @ k.T).reshape(T, g, -1)  # (T, g, BLK_S)
-    scores = jnp.where(msk[:, None, :], scores, NEG).reshape(T * g, -1)
-
-    m_old = m_ref[0, 0].reshape(T * g, -1)[:, :1]          # (T*g, 1)
-    m_new = jnp.maximum(m_old, jnp.max(scores, axis=-1, keepdims=True))
-    p = jnp.where(scores > NEG * 0.5, jnp.exp(scores - m_new), 0.0)
-    corr = jnp.where(m_old > NEG * 0.5, jnp.exp(m_old - m_new), 0.0)
-
-    l_old = l_ref[0, 0].reshape(T * g, -1)[:, :1]
-    l_new = l_old * corr + jnp.sum(p, axis=-1, keepdims=True)
-    l_ref[0, 0] = jnp.broadcast_to(l_new, (T * g, 128)).reshape(T, g, 128)
-    acc = acc_ref[0, 0].reshape(T * g, D)
-    acc_ref[0, 0] = (acc * corr + p @ v).reshape(T, g, D)
-    m_ref[0, 0] = jnp.broadcast_to(m_new, (T * g, 128)).reshape(T, g, 128)
-
-
 def masked_tree_attention_pallas(q: jnp.ndarray, k: jnp.ndarray,
                                  v: jnp.ndarray, mask: jnp.ndarray,
-                                 scale: float | None = None,
-                                 interpret: bool = True) -> jnp.ndarray:
+                                 *, interpret: bool,
+                                 scale: float | None = None
+                                 ) -> jnp.ndarray:
     """q: (B, T, H, D); k, v: (B, S, Hkv, D); mask: (B, T, S) per-query
     (tree-ancestor rows over the speculative block, validity-causal rows
     elsewhere).  S must be a BLK_S multiple and D 128-aligned (ops.py
-    pads).  T=1 with a (B, 1, S) mask reproduces the single-token kernel.
-    """
+    pads).  T=1 with a (B, 1, S) mask is single-token decode.
+
+    K/V are transposed head-major, (B, Hkv, S, D), so every block is a
+    (BLK_S, D) tile: the TPU lowering needs the last two block dims
+    tile-aligned or whole."""
     B, T, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
-    g = H // Hkv
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    qg = q.reshape(B, T, Hkv, g, D)
-    grid = (B, Hkv, S // BLK_S)
+    qh, mask_rows = _head_major(q, mask, Hkv)
+    R = qh.shape[2]
+    kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    acc_spec = pl.BlockSpec((None, None, R, D), lambda b, h, s: (b, h, 0, 0))
+    ml_spec = pl.BlockSpec((None, None, R, 128),
+                           lambda b, h, s: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, BLK_S, D),
+                           lambda b, h, s: (b, h, s, 0))
 
     acc, m, l = pl.pallas_call(
-        functools.partial(_tree_attn_kernel, scale=scale),
-        grid=grid,
+        functools.partial(_flash_kernel, scale=scale),
+        grid=(B, Hkv, S // BLK_S),
         in_specs=[
-            pl.BlockSpec((1, T, 1, g, D), lambda b, h, s: (b, 0, h, 0, 0)),
-            pl.BlockSpec((1, BLK_S, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, BLK_S, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, T, BLK_S), lambda b, h, s: (b, 0, s)),
+            acc_spec,
+            kv_spec,
+            kv_spec,
+            pl.BlockSpec((None, R, BLK_S), lambda b, h, s: (b, 0, s)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, T, g, D), lambda b, h, s: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, T, g, 128), lambda b, h, s: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, T, g, 128), lambda b, h, s: (b, h, 0, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, T, g, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, T, g, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, T, g, 128), jnp.float32),
-        ],
+        out_specs=[acc_spec, ml_spec, ml_spec],
+        out_shape=_acc_shapes(B, Hkv, R, D),
         interpret=interpret,
-    )(qg, k, v, mask)
-
-    l1 = l[..., :1]
-    out = jnp.where(l1 > 0, acc / jnp.maximum(l1, 1e-30), 0.0)
-    # (B, Hkv, T, g, D) -> (B, T, H, D)
-    return out.swapaxes(1, 2).reshape(B, T, H, D).astype(q.dtype)
+    )(qh, kh, vh, mask_rows)
+    return _normalize(acc, l, B, T, H, q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -200,16 +166,16 @@ def _paged_attn_kernel(table_ref, q_ref, k_ref, v_ref, mask_ref,
                        acc_ref, m_ref, l_ref, *, scale):
     # table_ref is consumed by the BlockSpec index maps (scalar prefetch);
     # the body is exactly the tree kernel's online softmax over one block.
-    _tree_attn_kernel(q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref,
-                      scale=scale)
+    _flash_kernel(q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref,
+                  scale=scale)
 
 
 def paged_flash_decode_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
                               v_pool: jnp.ndarray,
                               block_table: jnp.ndarray,
                               mask: jnp.ndarray,
-                              scale: float | None = None,
-                              interpret: bool = True) -> jnp.ndarray:
+                              *, interpret: bool,
+                              scale: float | None = None) -> jnp.ndarray:
     """q: (B, T, H, D); k_pool, v_pool: (P, bs, Hkv, D) block pools;
     block_table: (B, R) int32 pool block per row-local block (entries must
     be pre-clamped to [0, P) — unallocated blocks are mask-False anyway);
@@ -218,44 +184,43 @@ def paged_flash_decode_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
     Grid (B, Hkv, R): the minor axis walks the row's block table; the K/V
     index maps dereference ``table[b, r]`` so each pool block is DMA'd
     exactly once per (row, kv-head).  T=1 gives paged single-token decode;
-    T>1 with ancestor-mask rows gives paged tree-block decode.  On the TPU
-    path bs should be a multiple of 8 (sublane) and D 128-aligned
-    (ops.py pads D; bs is a build-time choice).
+    T>1 with ancestor-mask rows gives paged tree-block decode.  bs must be
+    a multiple of 8 (sublane) and D 128-aligned (ops.py pads D; bs is a
+    build-time choice).  The pools are transposed head-major and the mask
+    split per block, so every block is whole in its last two dims.
     """
     B, T, H, D = q.shape
     P, bs, Hkv, _ = k_pool.shape
-    R = block_table.shape[1]
-    g = H // Hkv
+    Rb = block_table.shape[1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    qg = q.reshape(B, T, Hkv, g, D)
-    tbl = block_table.reshape(-1).astype(jnp.int32)       # (B*R,)
+    qh, mask_rows = _head_major(q, mask, Hkv)
+    R = qh.shape[2]
+    mask_blocks = mask_rows.reshape(B, R, Rb, bs).transpose(0, 2, 1, 3)
+    kh, vh = k_pool.transpose(0, 2, 1, 3), v_pool.transpose(0, 2, 1, 3)
+    tbl = block_table.reshape(-1).astype(jnp.int32)       # (B*Rb,)
+    acc_spec = pl.BlockSpec((None, None, R, D),
+                            lambda b, h, r, t: (b, h, 0, 0))
+    ml_spec = pl.BlockSpec((None, None, R, 128),
+                           lambda b, h, r, t: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, bs, D),
+                           lambda b, h, r, t: (t[b * Rb + r], h, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hkv, R),
+        grid=(B, Hkv, Rb),
         in_specs=[
-            pl.BlockSpec((1, T, 1, g, D), lambda b, h, r, t: (b, 0, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, r, t: (t[b * R + r], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, r, t: (t[b * R + r], 0, h, 0)),
-            pl.BlockSpec((1, T, bs), lambda b, h, r, t: (b, 0, r)),
+            acc_spec,
+            kv_spec,
+            kv_spec,
+            pl.BlockSpec((None, None, R, bs),
+                         lambda b, h, r, t: (b, r, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, T, g, D), lambda b, h, r, t: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, T, g, 128), lambda b, h, r, t: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, 1, T, g, 128), lambda b, h, r, t: (b, h, 0, 0, 0)),
-        ],
+        out_specs=[acc_spec, ml_spec, ml_spec],
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_paged_attn_kernel, scale=scale),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, T, g, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, T, g, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, T, g, 128), jnp.float32),
-        ],
+        out_shape=_acc_shapes(B, Hkv, R, D),
         interpret=interpret,
-    )(tbl, qg, k_pool, v_pool, mask)
-
-    l1 = l[..., :1]
-    out = jnp.where(l1 > 0, acc / jnp.maximum(l1, 1e-30), 0.0)
-    return out.swapaxes(1, 2).reshape(B, T, H, D).astype(q.dtype)
+    )(tbl, qh, kh, vh, mask_blocks)
+    return _normalize(acc, l, B, T, H, q.dtype)

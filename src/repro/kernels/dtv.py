@@ -53,7 +53,7 @@ def _stats_kernel(x_ref, m_ref, s_ref):
     m_ref[...] = m_new
 
 
-def softmax_stats(logits: jnp.ndarray, interpret: bool = True):
+def softmax_stats(logits: jnp.ndarray, *, interpret: bool):
     """(R, V) -> (max (R, 1), sumexp (R, 1)); V, R padded by caller."""
     R, V = logits.shape
     grid = (R // BLK_R, V // BLK_V)
@@ -87,12 +87,12 @@ def _dtv_kernel(a_ref, b_ref, ma_ref, sa_ref, mb_ref, sb_ref, out_ref):
 
 
 def dtv_pallas(a_logits: jnp.ndarray, b_logits: jnp.ndarray,
-               interpret: bool = True) -> jnp.ndarray:
+               *, interpret: bool) -> jnp.ndarray:
     """(R, V) x 2 -> (R,) TV distance. Caller pads R to BLK_R and V to
     BLK_V multiples (padding lanes use NEG logits -> zero probability)."""
     R, V = a_logits.shape
-    ma, sa = softmax_stats(a_logits, interpret)
-    mb, sb = softmax_stats(b_logits, interpret)
+    ma, sa = softmax_stats(a_logits, interpret=interpret)
+    mb, sb = softmax_stats(b_logits, interpret=interpret)
     grid = (R // BLK_R, V // BLK_V)
     row = pl.BlockSpec((BLK_R, 1), lambda i, j: (i, 0))
     out = pl.pallas_call(

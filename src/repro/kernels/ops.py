@@ -1,9 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handle padding to tile boundaries, dtype plumbing, and backend selection:
-on TPU the kernels run compiled; on this CPU host they run in interpret
-mode (same kernel body, Python-executed) — correctness is validated against
-the ref.py oracles either way.
+on TPU the kernels run compiled; on the CPU backend (tests) they run in
+interpret mode (same kernel body, Python-executed) — correctness is
+validated against the ref.py oracles either way.  The choice is made when
+a wrapper is traced, never at import, and any other backend is refused:
+a kernel silently interpreted on an accelerator would hide the device.
 """
 from __future__ import annotations
 
@@ -11,15 +13,27 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.interpreters import pxla
 from jax.sharding import NamedSharding, PartitionSpec
 
 from . import attention as _attn
 from . import dtv as _dtv
 from . import verify as _verify
 from . import ref
+from ..sharding import context_mesh
 
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """Interpret mode for the backend this trace runs on: compiled on TPU,
+    interpreted on CPU, refused anywhere else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; the "
+        f"{backend!r} backend has neither (pass use_kernel=False for the "
+        "jnp reference path)")
 
 
 def _force_replicated(*arrays):
@@ -27,13 +41,13 @@ def _force_replicated(*arrays):
     operands it can run the kernel per-shard (partial softmax over a split
     head/seq dim — numerically wrong), not insert collectives.  Under an
     active multi-device mesh (the mesh-sharded serving path traces every
-    program inside ``with placement.mesh:`` — see Executor), constrain all
+    program inside ``placement.mesh_context()`` — see Executor), constrain all
     operands to replicated so the kernel always sees full arrays; XLA then
     places the gather collectives OUTSIDE the kernel.  With no mesh
     context (the trivial placement) this is a no-op and the lowering is
     byte-identical to the unmeshed path."""
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty or mesh.size == 1:
+    mesh = context_mesh()
+    if mesh is None:
         return arrays if len(arrays) > 1 else arrays[0]
     rep = NamedSharding(mesh, PartitionSpec())
     out = tuple(jax.lax.with_sharding_constraint(a, rep) for a in arrays)
@@ -62,7 +76,7 @@ def dtv(a_logits: jnp.ndarray, b_logits: jnp.ndarray,
     b = _pad_to(_pad_to(b_logits, _dtv.BLK_V, 1, _dtv.NEG),
                 _dtv.BLK_R, 0, _dtv.NEG)
     a, b = _force_replicated(a, b)
-    return _dtv.dtv_pallas(a, b, interpret=_INTERPRET)[:B]
+    return _dtv.dtv_pallas(a, b, interpret=_interpret())[:B]
 
 
 # ---------------------------------------------------------------------------
@@ -77,7 +91,7 @@ def verify_row_stats(logits: jnp.ndarray, cand: jnp.ndarray,
                 _verify.BLK_R, 0, _verify.NEG)
     c = _pad_to(cand.astype(jnp.int32), _verify.BLK_R, 0, 0)
     x, c = _force_replicated(x, c)
-    am, m, s, cl = _verify.verify_stats_pallas(x, c, interpret=_INTERPRET)
+    am, m, s, cl = _verify.verify_stats_pallas(x, c, interpret=_interpret())
     return am[:R], m[:R], s[:R], cl[:R]
 
 
@@ -95,7 +109,7 @@ def draft_topk(logits: jnp.ndarray, k: int, use_kernel: bool = True):
     x = _pad_to(_pad_to(logits, _verify.BLK_V, 1, _verify.NEG),
                 _verify.BLK_R, 0, _verify.NEG)
     x = _force_replicated(x)
-    vals, idx = _verify.topk_pallas(x, k, interpret=_INTERPRET)
+    vals, idx = _verify.topk_pallas(x, k, interpret=_interpret())
     return vals[:R], idx[:R]
 
 
@@ -119,13 +133,12 @@ def masked_decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     qp = _pad_to(q, 128, 2, 0.0)
     kp = _pad_to(k, 128, 3, 0.0)
     vp = _pad_to(v, 128, 3, 0.0)
-    S = k.shape[1]
     kp = _pad_to(kp, _attn.BLK_S, 1, 0.0)
     vp = _pad_to(vp, _attn.BLK_S, 1, 0.0)
     mp = _pad_to(mask, _attn.BLK_S, 1, False)
     qp, kp, vp, mp = _force_replicated(qp, kp, vp, mp)
     out = _attn.masked_decode_attention_pallas(
-        qp, kp, vp, mp, scale=scale, interpret=_INTERPRET)
+        qp, kp, vp, mp, scale=scale, interpret=_interpret())
     return out[:, :, :D]
 
 
@@ -151,7 +164,7 @@ def masked_tree_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     mp = _pad_to(mask, _attn.BLK_S, 2, False)
     qp, kp, vp, mp = _force_replicated(qp, kp, vp, mp)
     out = _attn.masked_tree_attention_pallas(
-        qp, kp, vp, mp, scale=scale, interpret=_INTERPRET)
+        qp, kp, vp, mp, scale=scale, interpret=_interpret())
     return out[:, :, :, :D]
 
 
@@ -187,5 +200,5 @@ def paged_decode_attention(q: jnp.ndarray, k_flat: jnp.ndarray,
     tbl = jnp.clip(block_table, 0, P - 1)
     qp, kp, vp, tbl, mask = _force_replicated(qp, kp, vp, tbl, mask)
     out = _attn.paged_flash_decode_pallas(
-        qp, kp, vp, tbl, mask, scale=scale, interpret=_INTERPRET)
+        qp, kp, vp, tbl, mask, scale=scale, interpret=_interpret())
     return out[:, :, :, :D]

@@ -64,7 +64,7 @@ def _verify_kernel(x_ref, cand_ref, am_ref, m_ref, s_ref, cl_ref):
 
 
 def verify_stats_pallas(logits: jnp.ndarray, cand: jnp.ndarray,
-                        interpret: bool = True):
+                        *, interpret: bool):
     """logits: (R, V) padded; cand: (R,) int32.
 
     Returns (argmax (R,), max (R,), sumexp (R,), cand_logit (R,))."""
@@ -130,7 +130,7 @@ def _topk_kernel(x_ref, v_ref, i_ref, *, K):
     i_ref[...] = ni
 
 
-def topk_pallas(logits: jnp.ndarray, k: int, interpret: bool = True):
+def topk_pallas(logits: jnp.ndarray, k: int, *, interpret: bool):
     """logits: (R, V) padded to tile boundaries; returns
     (values (R, k) f32, indices (R, k) i32), argmax tie-breaking."""
     R, V = logits.shape

@@ -258,7 +258,7 @@ def run_case(arch: str, shape_name: str, mesh_kind: str,
         fn, args, shards, cfg, meta = build_case(arch, shape_name, mesh,
                                                  multi, kv_quant=kv_quant)
         rec.update(meta)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=shards)
             lowered = jitted.lower(*args)
             t_lower = time.perf_counter() - t0

@@ -47,6 +47,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @jax.tree_util.register_dataclass
@@ -79,7 +80,7 @@ def make_state(batch: int, max_len: int, layers: Dict[str, Any]) -> ModelState:
     )
 
 
-_BIG = jnp.int32(2 ** 30)
+_BIG = np.int32(2 ** 30)      # numpy: no device array at import
 
 
 def _append_positions(state, valid, spec_depth):

@@ -20,6 +20,7 @@ from . import kv_cache as kvc
 from . import layers as nn
 from .config import ModelConfig
 from . import transformer as tf
+from ..sharding import context_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -62,19 +63,8 @@ def moe_ffn_axes(cfg: ModelConfig, prefix=("layers",)):
 def _maybe_constrain(x, spec):
     """with_sharding_constraint when a ('data','model') mesh is in context
     (dry-run / pod execution); no-op on the bare CPU test path."""
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        names = set(getattr(pm, "axis_names", ()) or ())
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and getattr(am, "axis_names", ()):
-            names |= set(am.axis_names)
-        if {"data", "model"} <= names:
-            return jax.lax.with_sharding_constraint(x, spec)
-    except (ImportError, AttributeError, TypeError):
-        # probing unstable jax internals across versions; any of these
-        # just means "no mesh in context" — fall through to the no-op
-        pass
+    if _ep_mesh() is not None:
+        return jax.lax.with_sharding_constraint(x, spec)
     return x
 
 
@@ -152,22 +142,14 @@ def moe_ffn(p, cfg: ModelConfig, x: jnp.ndarray):
 # ---------------------------------------------------------------------------
 def _ep_mesh():
     """The ('data','model') mesh in context, or None (CPU test path)."""
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and {"data", "model"} <= set(
-                getattr(pm, "axis_names", ()) or ()):
-            return pm
-    except (ImportError, AttributeError, TypeError):
-        # same unstable-internals probe as _maybe_constrain: failure
-        # means "no usable mesh", which is the CPU test path
-        pass
+    mesh = context_mesh()
+    if mesh is not None and {"data", "model"} <= set(mesh.axis_names):
+        return mesh
     return None
 
 
 def moe_ffn_ep(p, cfg: ModelConfig, x: jnp.ndarray, mesh):
     """Expert-parallel MoE FFN under shard_map. x: (B, T, D)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -242,13 +224,13 @@ def moe_ffn_ep(p, cfg: ModelConfig, x: jnp.ndarray, mesh):
                                 for k in ("gate", "up", "down")})
     shared_spec = jax.tree.map(lambda _: P(), shared_p)
     tok_axes = data_axes + ("model",)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(tok_axes, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None),
                   shared_spec),
         out_specs=(P(tok_axes, None), P()),
-        check_rep=False)
+        check_vma=False)
     xf = x.reshape(B * T, D)
     out, aux = fn(xf, p["router"], p["w_gate"], p["w_up"], p["w_down"],
                   shared_p)

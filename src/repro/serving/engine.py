@@ -94,6 +94,10 @@ class ServingMetrics:
     slo_goodput_rps: float = float("nan")   # SLO-met requests per second
     request_slo_attainment: float = float("nan")  # met / ALL offered
     num_shed: int = 0               # dropped by the admission shed policy
+    # mean host syncs of the cycles that ran every group as one fused
+    # device program (the one-transfer contract: 1 per group); NaN when
+    # no cycle was fused (legacy engine, fused=False)
+    fused_cycle_host_syncs: float = float("nan")
 
     def as_dict(self) -> Dict[str, float]:
         return dataclasses.asdict(self)
@@ -165,6 +169,8 @@ class ServingEngine:
         # every request's latency)
         self._router = ChainRouter(self.pool, self.target,
                                    **self.router_kwargs)
+        # host syncs of each all-fused cycle of the current run
+        self._fused_syncs: List[int] = []
 
     def run(self, requests: Sequence[Request]) -> ServingMetrics:
         reqs = sorted(requests, key=lambda r: r.arrival_s)
@@ -182,6 +188,7 @@ class ServingEngine:
         # then (the A/B baseline in benchmarks/goodput_ab.py)
         self._router.scheduler.slo_aware = (
             self.slo_aware if self.slo_aware is not None else has_slo)
+        self._fused_syncs = []
         if self.continuous:
             acc_lens = self._run_continuous(reqs)
         else:
@@ -291,6 +298,8 @@ class ServingEngine:
             rep = sess.run_cycle()
             clock += rep.wall_s
             cycles += 1
+            if rep.fused:
+                self._fused_syncs.append(rep.host_syncs)
             if rep.commits.any():
                 acc_lens.append(rep.acc_mean)
             for s in range(B):
@@ -426,4 +435,6 @@ class ServingEngine:
             slo_goodput_rps=sum(r.slo_met for r in done) / rate_denom,
             request_slo_attainment=attain,
             num_shed=num_shed,
+            fused_cycle_host_syncs=(float(np.mean(self._fused_syncs))
+                                    if self._fused_syncs else float("nan")),
         )

@@ -14,7 +14,8 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (AbstractMesh, Mesh, NamedSharding,
+                          PartitionSpec as P)
 
 
 # Priority-ordered mesh-axis candidates per logical axis.  Each entry is a
@@ -104,6 +105,14 @@ def build_sharding(axes_tree: Any, shape_tree: Any, mesh: Mesh,
                             isinstance(x, tuple)
                             and all(isinstance(e, (str, type(None)))
                                     for e in x)))
+
+
+def context_mesh() -> Optional[AbstractMesh]:
+    """The multi-device mesh set by ``jax.set_mesh`` around the current
+    trace (abstract: it is read while tracing), or None when there is no
+    mesh or a single device that shards nothing."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
 
 
 def shape_tree_of(tree: Any) -> Any:

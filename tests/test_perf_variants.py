@@ -49,8 +49,10 @@ def test_ep_moe_matches_dense(tmp_path):
         p = moe.init_moe_ffn(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
         y_ref, _ = moe.moe_ffn(p, cfg, x)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh:
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        with jax.set_mesh(mesh):
             y_ep, _ = jax.jit(lambda p, x: moe.moe_ffn_ep(p, cfg, x,
                                                           mesh))(p, x)
         rel = float(jnp.max(jnp.abs(y_ep - y_ref))
