@@ -1,0 +1,7 @@
+"""Benchmark harness of the serving path: ``python3 bench/run.py``.
+
+Driven by data: ``BENCHMARK.json`` names each cell's configuration
+(``bench/configs/<config>.json``), traffic mix (``bench/traffic/<mix>.json``)
+and per-layer metrics (``bench/metrics/<metric>.py``); adding one is adding
+a file and a manifest entry.
+"""
