@@ -149,7 +149,7 @@ def serve_phase(pool: ModelPool, target: str, name: str, router_kwargs,
     """Warm-up pass + measured pass of one router setting through
     ``ServingEngine``, then the reference check of the measured outputs.
     Returns the phase's report."""
-    prof = PerformanceProfiler(trace_cap=512)
+    prof = PerformanceProfiler()
     eng = ServingEngine(pool, target, batch_size=slots,
                         router_kwargs=dict(router_kwargs, profiler=prof),
                         mesh=None if pool.placement.is_trivial

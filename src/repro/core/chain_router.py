@@ -109,6 +109,8 @@ class CycleReport:
         dataclasses.field(default_factory=list)
     fused: bool = False           # every group ran as one device program
     host_syncs: int = 0           # profiler host_sync count of this cycle
+    wait_s: float = 0.0           # seconds of wall_s spent in cycle.wait
+    per_op_groups: int = 0        # groups that ran the per-op path
 
 
 class ChainRouter:
@@ -784,8 +786,7 @@ class RouterSession:
         self.committed = 0
         # diagnostics ring: one (chain, window) entry per sub-cycle group
         # — bounded, or an indefinite serving session leaks it at
-        # O(groups · cycles) (same accumulator class as the profiler
-        # trace, which is capped for the same reason)
+        # O(groups · cycles)
         self.chain_history: collections.deque = \
             collections.deque(maxlen=4096)
         # lazy chain membership: model -> (B,) bool, True where the
@@ -1141,14 +1142,15 @@ class RouterSession:
             self._wp_cache[m] = info
         return bool(int(np.max(info[0])) + needed <= st.capacity)
 
-    def _run_fused_group(self, choice: ChainChoice, gmask: np.ndarray,
-                         slot_keys: Optional[Sequence[str]]
-                         ) -> Optional[np.ndarray]:
-        """Run one sub-cycle group as a single device program.  Returns
-        per-row raw commits, or None when the group must fall back to the
-        per-op path this cycle (capacity pressure, or a catch-up gap wider
-        than the program's static prefix — both are the legacy path's
-        escape hatches)."""
+    def _prepare_fused(self, choice: ChainChoice, gmask: np.ndarray
+                       ) -> Tuple[Optional[FusedCycleRequest],
+                                  Optional[str]]:
+        """Preflight and inputs of one sub-cycle group as a single device
+        program: (the program's request, None), or (None, why the group
+        falls back to the per-op path this cycle: ``untimed``, ``gap`` —
+        a catch-up gap wider than the program's static prefix — or
+        ``capacity``; the last two are the legacy path's escape
+        hatches)."""
         r = self.router
         chain = choice.chain
         tree = choice.tree if (choice.tree is not None
@@ -1161,7 +1163,7 @@ class RouterSession:
         # (benchmarks/routing_ab.py pins the resulting decoy-kill
         # behaviour under the fused default)
         if not self._chain_timed(chain, tree):
-            return None
+            return None, "untimed"
         depth = tree.depth_levels if tree is not None else choice.window
         # prefix-width bound: the worst-case consensus gap is the target's
         # max accepted length (W + N - 2 linear, D tree); +1 for t_last,
@@ -1172,7 +1174,7 @@ class RouterSession:
             lens = self._cached_lengths(m)
             gap = np.where(gmask, (self.seq_len - 1) - lens, 0)
             if gap.min() < 0 or gap.max() > p_max - 1:
-                return None          # needs the re-prefill escape
+                return None, "gap"   # needs the re-prefill escape
             gmax = max(gmax, int(gap.max()))
         # pow-2 prefix-width buckets (min 2 = [t_last] + 1 gap slot), like
         # the per-op path's gap buckets: the steady-state cycle (gap 0)
@@ -1187,19 +1189,27 @@ class RouterSession:
         needed = P + block + len(chain)
         for m in chain:
             if not self._fused_capacity_ok(m, needed, gmask):
-                return None          # needs the defrag/rebuild escape
+                return None, "capacity"  # needs the defrag/rebuild escape
         self._sync_device()
         rngs = tuple(r._next_rng() for _ in chain)
+        return FusedCycleRequest(
+            chain=chain, request_id=self.session_id,
+            window=choice.window, tree=tree, prefix_width=P, eos=r.eos,
+            seq=self._dev["seq"], seq_len=self._dev["seq_len"],
+            prompt_len=self._dev["prompt_len"],
+            budget=self._dev["budget"], active=self._dev["active"],
+            gmask=jnp.asarray(gmask), rngs=rngs, greedy=r.greedy,
+            temperature=r.temperature), None
+
+    def _run_fused_group(self, req: FusedCycleRequest, choice: ChainChoice,
+                         gmask: np.ndarray,
+                         slot_keys: Optional[Sequence[str]]) -> np.ndarray:
+        """Run one sub-cycle group as a single device program (``req``
+        from ``_prepare_fused``).  Returns per-row raw commits."""
+        r = self.router
         ok = False
         try:
-            bufs, s = r.executor.fused_cycle(FusedCycleRequest(
-                chain=chain, request_id=self.session_id,
-                window=choice.window, tree=tree, prefix_width=P, eos=r.eos,
-                seq=self._dev["seq"], seq_len=self._dev["seq_len"],
-                prompt_len=self._dev["prompt_len"],
-                budget=self._dev["budget"], active=self._dev["active"],
-                gmask=jnp.asarray(gmask), rngs=rngs, greedy=r.greedy,
-                temperature=r.temperature))
+            bufs, s = r.executor.fused_cycle(req)
             ok = True
         finally:
             # on ANY failure (including KeyboardInterrupt) the donated
@@ -1211,6 +1221,18 @@ class RouterSession:
             if not ok:
                 self._dev = None
                 self._dev_stale = True
+        with r.profiler.span("cycle.mirror"):
+            return self._mirror_summary(choice, gmask, slot_keys, bufs, s)
+
+    def _mirror_summary(self, choice: ChainChoice, gmask: np.ndarray,
+                        slot_keys: Optional[Sequence[str]], bufs,
+                        s) -> np.ndarray:
+        """Host mirror of one fused group's summary, and the similarity
+        and acceptance feedback; returns per-row raw commits."""
+        r = self.router
+        chain = choice.chain
+        tree = choice.tree if (choice.tree is not None
+                               and len(chain) > 1) else None
         self._dev.update(bufs)
         # --- mirror the one-transfer summary onto the host ----------------
         cnum = s.n_committed.astype(np.int64)
@@ -1258,60 +1280,95 @@ class RouterSession:
 
         With ``router.fused`` (default) each group is one device program
         and one host transfer; every ``profile_every``-th cycle instead
-        runs the per-op path to refresh the scheduler's timings."""
+        runs the per-op path to refresh the scheduler's timings.
+
+        The call is one ``cycle`` span whose phases are child spans:
+        ``cycle.schedule``, then per group ``cycle.prepare``,
+        ``cycle.dispatch``, ``cycle.wait``, ``cycle.mirror`` (or
+        ``cycle.per_op``), then ``cycle.finish``.  A group that falls
+        back to the per-op path counts ``fallback.<reason>``."""
         r = self.router
         B = self.num_slots
         if not self.active.any():
             return CycleReport(np.zeros(B, np.int64), 0.0, (), 0, 0.0)
-        self._reschedule()
-        # group slots by assigned (chain, window, tree shape)
-        groups: Dict[tuple, np.ndarray] = {}
-        order: List[tuple] = []
-        for s in np.where(self.active)[0]:
-            c = self._slot_choice[s]
-            key = (c.chain, c.window,
-                   c.tree.branching if c.tree is not None else None)
-            if key not in groups:
-                groups[key] = np.zeros(B, bool)
-                order.append(key)
-            groups[key][s] = True
-        slot_keys = ([self._skey(s) for s in range(B)]
-                     if r.slot_routing else None)
-        pre_active = self.active.copy()
-        gen_before = (self.seq_len - self.prompt_len).copy()
-        n_acc = np.zeros(B, np.int64)
-        ginfo: List[Tuple[Tuple[str, ...], int, int]] = []
-        profiling = (not r.fused) or (r.profile_every > 0
-                                      and self.steps % r.profile_every == 0)
-        all_fused = True
-        syncs0 = r.profiler.counters["host_sync"]
-        t0 = _time.perf_counter()
-        for key in order:
-            gmask = groups[key] & self.active
-            if not gmask.any():
-                continue
-            first = int(np.where(gmask)[0][0])
-            choice = self._slot_choice[first]
-            self._ensure_members(choice.chain, gmask)
-            acc = None
-            if r.fused and not profiling:
-                acc = self._run_fused_group(choice, gmask, slot_keys)
-            if acc is None:          # profiling cycle or fused fallback
-                all_fused = False
-                acc = r._one_cycle(choice.chain, choice.window,
-                                   self.session_id, self.seq,
-                                   self.seq_len, gmask, tree=choice.tree,
-                                   members=self._members,
-                                   slot_keys=slot_keys)
-                # the per-op path mutated host state directly: device
-                # buffers and summary-fed cursor views are stale (a later
-                # fused group this cycle must re-upload)
-                self._dev_stale = True
-                self._invalidate_state_caches()
-            n_acc += np.asarray(acc, np.int64)   # groups are row-disjoint
-            self.chain_history.append((choice.chain, choice.window))
-            ginfo.append((choice.chain, choice.window, int(gmask.sum())))
-        wall = _time.perf_counter() - t0
+        prof = r.profiler
+        with prof.span("cycle"):
+            with prof.span("cycle.schedule"):
+                self._reschedule()
+                # group slots by assigned (chain, window, tree shape)
+                groups: Dict[tuple, np.ndarray] = {}
+                order: List[tuple] = []
+                for s in np.where(self.active)[0]:
+                    c = self._slot_choice[s]
+                    key = (c.chain, c.window,
+                           c.tree.branching if c.tree is not None else None)
+                    if key not in groups:
+                        groups[key] = np.zeros(B, bool)
+                        order.append(key)
+                    groups[key][s] = True
+                slot_keys = ([self._skey(s) for s in range(B)]
+                             if r.slot_routing else None)
+            pre_active = self.active.copy()
+            gen_before = (self.seq_len - self.prompt_len).copy()
+            n_acc = np.zeros(B, np.int64)
+            ginfo: List[Tuple[Tuple[str, ...], int, int]] = []
+            profiling = r.fused and (r.profile_every > 0
+                                     and self.steps % r.profile_every == 0)
+            per_op = 0
+            syncs0 = prof.counters["host_sync"]
+            wait0 = prof.spans.get("cycle.wait", (0, 0.0))[1]
+            t0 = _time.perf_counter()
+            for key in order:
+                gmask = groups[key] & self.active
+                if not gmask.any():
+                    continue
+                first = int(np.where(gmask)[0][0])
+                choice = self._slot_choice[first]
+                chain_name = "+".join(choice.chain)
+                req, fallback = None, None
+                with prof.span("cycle.prepare", chain=chain_name):
+                    self._ensure_members(choice.chain, gmask)
+                    if profiling:
+                        fallback = "profiling"
+                    elif r.fused:
+                        req, fallback = self._prepare_fused(choice, gmask)
+                prof.count("groups")
+                if req is not None:
+                    acc = self._run_fused_group(req, choice, gmask,
+                                                slot_keys)
+                else:                # per-op path or a fused fallback
+                    if fallback is not None:
+                        prof.count(f"fallback.{fallback}")
+                    per_op += 1
+                    with prof.span("cycle.per_op", chain=chain_name):
+                        acc = r._one_cycle(choice.chain, choice.window,
+                                           self.session_id, self.seq,
+                                           self.seq_len, gmask,
+                                           tree=choice.tree,
+                                           members=self._members,
+                                           slot_keys=slot_keys)
+                    # the per-op path mutated host state directly: device
+                    # buffers and summary-fed cursor views are stale (a
+                    # later fused group this cycle must re-upload)
+                    self._dev_stale = True
+                    self._invalidate_state_caches()
+                n_acc += np.asarray(acc, np.int64)  # groups are row-disjoint
+                self.chain_history.append((choice.chain, choice.window))
+                ginfo.append((choice.chain, choice.window, int(gmask.sum())))
+            wall = _time.perf_counter() - t0
+            with prof.span("cycle.finish"):
+                return self._finish_cycle(
+                    n_acc, wall, pre_active, gen_before, ginfo, per_op,
+                    host_syncs=int(prof.counters["host_sync"] - syncs0),
+                    wait_s=prof.spans.get("cycle.wait", (0, 0.0))[1] - wait0)
+
+    def _finish_cycle(self, n_acc: np.ndarray, wall: float,
+                      pre_active: np.ndarray, gen_before: np.ndarray,
+                      ginfo: List[Tuple[Tuple[str, ...], int, int]],
+                      per_op: int, host_syncs: int,
+                      wait_s: float) -> CycleReport:
+        """Load signal, termination and the report of one cycle."""
+        r = self.router
         # cycle-latency EMA: the load signal's "seconds a queued request
         # waits per cycle boundary" (admission runs between cycles)
         r.profiler.record("cycle_wall", "session", wall)
@@ -1334,9 +1391,9 @@ class RouterSession:
         self.committed += int(survived.sum())
         lead = ginfo[0] if ginfo else ((), 0, 0)
         return CycleReport(n_acc, wall, lead[0], lead[1], acc_mean,
-                           groups=ginfo, fused=all_fused and bool(ginfo),
-                           host_syncs=int(r.profiler.counters["host_sync"]
-                                          - syncs0))
+                           groups=ginfo, fused=per_op == 0 and bool(ginfo),
+                           host_syncs=host_syncs, wait_s=wait_s,
+                           per_op_groups=per_op)
 
     def generated(self, slot: int) -> np.ndarray:
         """The slot's committed output tokens so far (prompt excluded)."""

@@ -466,8 +466,8 @@ class Executor:
             logits, state = self._jit_cache[key](
                 params, state, jnp.asarray(req.tokens),
                 jnp.asarray(req.valid), req.extras)
-            logits = jax.block_until_ready(logits)
-        self.profiler.count("host_sync")
+            with self.profiler.wait():
+                logits = jax.block_until_ready(logits)
         self.states.create(sid, state, layer_axes=state_axes.layers,
                            sharding=sharding)
         probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
@@ -488,8 +488,8 @@ class Executor:
             logits, state = fwd_last(params, state,
                                      jnp.asarray(req.tokens),
                                      jnp.asarray(req.valid), {})
-            logits = jax.block_until_ready(logits)
-        self.profiler.count("host_sync")
+            with self.profiler.wait():
+                logits = jax.block_until_ready(logits)
         self.states.update(sid, state)
         probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
         return np.asarray(probs)
@@ -538,18 +538,19 @@ class Executor:
         rng = self._req_rng(req.rng, req.greedy, "draft")
         f = self._draft_scan(req.model, req.window, req.greedy,
                              req.temperature)
-        t0 = time.perf_counter()
-        with self._mctx():
-            toks, probs, state = f(params, state,
-                                   jnp.asarray(req.prefix_tokens),
-                                   jnp.asarray(req.prefix_valid),
-                                   jnp.asarray(req.active), rng)
-        toks = jax.block_until_ready(toks)
-        dt = time.perf_counter() - t0
+        with self.profiler.span("op.draft", model=self._pq(req.model)):
+            t0 = time.perf_counter()
+            with self._mctx():
+                toks, probs, state = f(params, state,
+                                       jnp.asarray(req.prefix_tokens),
+                                       jnp.asarray(req.prefix_valid),
+                                       jnp.asarray(req.active), rng)
+            with self.profiler.wait():
+                toks = jax.block_until_ready(toks)
+            dt = time.perf_counter() - t0
         # amortized per-token draft time feeds the scheduler's T_i
         self.profiler.record("decode1", self._pq(req.model),
                              dt / req.window, tokens=req.window)
-        self.profiler.count("host_sync")
         self.states.update(sid, state)
         return np.asarray(toks), np.asarray(probs)
 
@@ -569,17 +570,18 @@ class Executor:
             [req.prefix_valid, np.ones_like(req.candidates, bool)], axis=1)
         bvalid = jnp.asarray(bvalid) & active[:, None]
 
-        t0 = time.perf_counter()
-        with self._mctx():
-            logits, state = fwd_all(params, state, jnp.asarray(block),
-                                    bvalid, {})
-        logits = jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
+        with self.profiler.span("op.verify", model=self._pq(req.model)):
+            t0 = time.perf_counter()
+            with self._mctx():
+                logits, state = fwd_all(params, state, jnp.asarray(block),
+                                        bvalid, {})
+            with self.profiler.wait():
+                logits = jax.block_until_ready(logits)
+            dt = time.perf_counter() - t0
         self.profiler.record("verify", self._pq(req.model), dt, tokens=Tc,
                              block=Tc + 1)
         # amortized per-token verify time (the decode1 analogue)
         self.profiler.record("verify1", self._pq(req.model), dt / (Tc + 1))
-        self.profiler.count("host_sync")
         self.states.update(sid, state)
 
         vlogits = logits[:, G1 - 1:]             # (B, Tc+1, V)
@@ -614,8 +616,8 @@ class Executor:
         with self.profiler.timed("rollback", self._pq(req.model),
                                  tokens=int(req.r.sum())), self._mctx():
             state = self._rollback(req.model)(state, jnp.asarray(req.r))
-            jax.block_until_ready(state.write_ptr)
-        self.profiler.count("host_sync")
+            with self.profiler.wait():
+                jax.block_until_ready(state.write_ptr)
         self.states.update(sid, state)
 
     # ------------------------------------------------------------------
@@ -648,14 +650,17 @@ class Executor:
         rng = self._req_rng(req.rng, req.greedy, "draft_tree")
         f = self._draft_tree(req.model, req.tree, req.greedy,
                              req.temperature)
-        t0 = time.perf_counter()
-        with self._mctx():
-            toks, probs, state = f(params, state,
-                                   jnp.asarray(req.prefix_tokens),
-                                   jnp.asarray(req.prefix_valid),
-                                   jnp.asarray(req.active), rng)
-        toks = jax.block_until_ready(toks)
-        dt = time.perf_counter() - t0
+        with self.profiler.span("op.draft_tree",
+                                model=self._pq(req.model)):
+            t0 = time.perf_counter()
+            with self._mctx():
+                toks, probs, state = f(params, state,
+                                       jnp.asarray(req.prefix_tokens),
+                                       jnp.asarray(req.prefix_valid),
+                                       jnp.asarray(req.active), rng)
+            with self.profiler.wait():
+                toks = jax.block_until_ready(toks)
+            dt = time.perf_counter() - t0
         # per-LEVEL wall time keyed by the full branching profile (meta
         # block -> EMA key): a level forward decodes several sibling
         # nodes, so feeding it into the per-token decode1 EMA would
@@ -668,7 +673,6 @@ class Executor:
         # amortized per-node draft time (the decode1 analogue for trees)
         self.profiler.record("decode1_tree", self._pq(req.model),
                              dt / req.tree.num_nodes)
-        self.profiler.count("host_sync")
         self.states.update(sid, state)
         return np.asarray(toks), np.asarray(probs)
 
@@ -721,16 +725,19 @@ class Executor:
             [req.prefix_valid, np.ones_like(req.candidates, bool)], axis=1)
         bvalid = jnp.asarray(bvalid) & active[:, None]
         fwd = self._fwd_tree(req.model, req.tree, G1)
-        t0 = time.perf_counter()
-        with self._mctx():
-            logits, state = fwd(params, state, jnp.asarray(block), bvalid)
-        logits = jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
+        with self.profiler.span("op.verify_tree",
+                                model=self._pq(req.model)):
+            t0 = time.perf_counter()
+            with self._mctx():
+                logits, state = fwd(params, state, jnp.asarray(block),
+                                    bvalid)
+            with self.profiler.wait():
+                logits = jax.block_until_ready(logits)
+            dt = time.perf_counter() - t0
         self.profiler.record("verify", self._pq(req.model), dt, tokens=N,
                              block=N + 1)
         # amortized per-node verify time (the decode1 analogue)
         self.profiler.record("verify1", self._pq(req.model), dt / (N + 1))
-        self.profiler.count("host_sync")
         self.states.update(sid, state)
 
         vlogits = logits[:, G1 - 1:]                 # (B, N+1, V)
@@ -791,67 +798,76 @@ class Executor:
             B = seq.shape[0]
             run = active & gmask
             sl32 = seq_len.astype(jnp.int32)
-            prefixes = [_gap_prefix_dev(st, seq, sl32, run, P)
-                        for st in states]
+            with jax.named_scope("gap_prefix"):
+                prefixes = [_gap_prefix_dev(st, seq, sl32, run, P)
+                            for st in states]
             if N == 1:
                 pfx, pval = prefixes[0]
-                toks, _probs, st = draft_body(params[0], states[0], pfx,
-                                              pval, run, rngs[0])
+                with jax.named_scope("decode"):
+                    toks, _probs, st = draft_body(params[0], states[0], pfx,
+                                                  pval, run, rngs[0])
                 states[0] = st
-                seq, new_len, slab, cnum = _commit_dev(
-                    seq, sl32, run, jnp.zeros((B, 0), jnp.int32),
-                    jnp.zeros((B,), jnp.int32), toks[:, 0], C)
+                with jax.named_scope("commit"):
+                    seq, new_len, slab, cnum = _commit_dev(
+                        seq, sl32, run, jnp.zeros((B, 0), jnp.int32),
+                        jnp.zeros((B,), jnp.int32), toks[:, 0], C)
                 accepts = jnp.zeros((0, B), jnp.int32)
                 dtvs = jnp.zeros((0, B), jnp.float32)
             else:
                 pfx, pval = prefixes[0]
-                cand, cprobs, st = draft_body(params[0], states[0], pfx,
-                                              pval, run, rngs[0])
+                with jax.named_scope("draft"):
+                    cand, cprobs, st = draft_body(params[0], states[0], pfx,
+                                                  pval, run, rngs[0])
                 states[0] = st
                 cand, cprobs = rs(cand), rs(cprobs)
                 valid_len = jnp.full((B,), W, jnp.int32)
                 ks, dts = [], []
                 res = None
                 for j in range(1, N):
-                    vpfx, vpval = prefixes[j]
-                    block = jnp.concatenate([vpfx, cand], axis=1)
-                    bvalid = jnp.concatenate(
-                        [vpval, jnp.ones(cand.shape, bool)],
-                        axis=1) & run[:, None]
-                    logits, st = lms[j].decode(params[j], states[j], block,
-                                               valid=bvalid,
-                                               logits_mode="all")
-                    states[j] = st
-                    vlogits = logits[:, P - 1:]
-                    if greedy:
-                        res = ver.verify_greedy(cand, vlogits, cprobs, run)
-                    else:
-                        res = ver.verify_sampling(
-                            cand, vlogits, cprobs, rngs[j],
-                            temperature=temperature, active=run,
-                            valid_len=valid_len)
-                    ks.append(res.num_accepted)
-                    dts.append(res.dtv)
-                    if j < N - 1:
-                        cand, cprobs, valid_len = ver.splice_candidates(
-                            cand, cprobs, res)
-                        cand, cprobs = rs(cand), rs(cprobs)
+                    with jax.named_scope(f"verify.{j}"):
+                        vpfx, vpval = prefixes[j]
+                        block = jnp.concatenate([vpfx, cand], axis=1)
+                        bvalid = jnp.concatenate(
+                            [vpval, jnp.ones(cand.shape, bool)],
+                            axis=1) & run[:, None]
+                        logits, st = lms[j].decode(params[j], states[j],
+                                                   block, valid=bvalid,
+                                                   logits_mode="all")
+                        states[j] = st
+                        vlogits = logits[:, P - 1:]
+                        if greedy:
+                            res = ver.verify_greedy(cand, vlogits, cprobs,
+                                                    run)
+                        else:
+                            res = ver.verify_sampling(
+                                cand, vlogits, cprobs, rngs[j],
+                                temperature=temperature, active=run,
+                                valid_len=valid_len)
+                        ks.append(res.num_accepted)
+                        dts.append(res.dtv)
+                        if j < N - 1:
+                            cand, cprobs, valid_len = ver.splice_candidates(
+                                cand, cprobs, res)
+                            cand, cprobs = rs(cand), rs(cprobs)
                 k_n = ks[-1]
                 ks_arr = jnp.stack(ks)                   # (N-1, B)
-                rbs = ver.consensus_rollbacks(ks_arr, W, run)
-                for j in range(N - 1):
-                    states[j] = lms[j].rollback(states[j], rbs[j])
-                states[N - 1] = lms[N - 1].rollback(
-                    states[N - 1], res.rollback.astype(jnp.int32))
-                seq, new_len, slab, cnum = _commit_dev(
-                    seq, sl32, run, cand, k_n, res.next_token, C)
+                with jax.named_scope("rollback"):
+                    rbs = ver.consensus_rollbacks(ks_arr, W, run)
+                    for j in range(N - 1):
+                        states[j] = lms[j].rollback(states[j], rbs[j])
+                    states[N - 1] = lms[N - 1].rollback(
+                        states[N - 1], res.rollback.astype(jnp.int32))
+                with jax.named_scope("commit"):
+                    seq, new_len, slab, cnum = _commit_dev(
+                        seq, sl32, run, cand, k_n, res.next_token, C)
                 accepts = ks_arr.astype(jnp.int32)
                 dtvs = jnp.stack(dts).astype(jnp.float32)
-            new_seq_len, new_active = _terminate_dev(
-                slab, run, sl32, new_len,
-                prompt_len.astype(jnp.int32), budget.astype(jnp.int32),
-                active, eos)
-            lengths, wps, fts, nbs = _state_summary(states)
+            with jax.named_scope("commit"):
+                new_seq_len, new_active = _terminate_dev(
+                    slab, run, sl32, new_len,
+                    prompt_len.astype(jnp.int32), budget.astype(jnp.int32),
+                    active, eos)
+                lengths, wps, fts, nbs = _state_summary(states)
             summary = FusedSummary(slab, cnum, new_seq_len, new_active,
                                    accepts, dtvs, lengths, wps, fts, nbs)
             return tuple(states), seq, new_seq_len, new_active, summary
@@ -882,11 +898,13 @@ class Executor:
             B = seq.shape[0]
             run = active & gmask
             sl32 = seq_len.astype(jnp.int32)
-            prefixes = [_gap_prefix_dev(st, seq, sl32, run, P)
-                        for st in states]
+            with jax.named_scope("gap_prefix"):
+                prefixes = [_gap_prefix_dev(st, seq, sl32, run, P)
+                            for st in states]
             pfx, pval = prefixes[0]
-            cand, cprobs, st = draft_body(params[0], states[0], pfx, pval,
-                                          run, rngs[0])
+            with jax.named_scope("draft"):
+                cand, cprobs, st = draft_body(params[0], states[0], pfx,
+                                              pval, run, rngs[0])
             states[0] = st
             cand, cprobs = rs(cand), rs(cprobs)
             node_valid = jnp.broadcast_to(run[:, None], (B, NT))
@@ -894,42 +912,46 @@ class Executor:
             res = None
             for j in range(1, N):
                 final = j == N - 1
-                vpfx, vpval = prefixes[j]
-                block = jnp.concatenate([vpfx, cand], axis=1)
-                bvalid = jnp.concatenate(
-                    [vpval, jnp.ones(cand.shape, bool)],
-                    axis=1) & run[:, None]
-                logits, st = lms[j].decode(params[j], states[j], block,
-                                           valid=bvalid, logits_mode="all",
-                                           spec_depth=spec_depth,
-                                           spec_attend=spec_attend)
-                states[j] = st
-                vlogits = logits[:, P - 1:]
-                res = ver.verify_tree(tree, cand, vlogits, node_valid,
-                                      candidate_probs=cprobs, key=rngs[j],
-                                      greedy=greedy,
-                                      temperature=temperature, active=run,
-                                      final=final)
-                acc_mats.append(res.accept)
-                ks.append(res.num_accepted)
-                dts.append(res.dtv)
-                if not final:
-                    node_valid = node_valid & res.accept
+                with jax.named_scope(f"verify.{j}"):
+                    vpfx, vpval = prefixes[j]
+                    block = jnp.concatenate([vpfx, cand], axis=1)
+                    bvalid = jnp.concatenate(
+                        [vpval, jnp.ones(cand.shape, bool)],
+                        axis=1) & run[:, None]
+                    logits, st = lms[j].decode(params[j], states[j], block,
+                                               valid=bvalid,
+                                               logits_mode="all",
+                                               spec_depth=spec_depth,
+                                               spec_attend=spec_attend)
+                    states[j] = st
+                    vlogits = logits[:, P - 1:]
+                    res = ver.verify_tree(tree, cand, vlogits, node_valid,
+                                          candidate_probs=cprobs,
+                                          key=rngs[j], greedy=greedy,
+                                          temperature=temperature,
+                                          active=run, final=final)
+                    acc_mats.append(res.accept)
+                    ks.append(res.num_accepted)
+                    dts.append(res.dtv)
+                    if not final:
+                        node_valid = node_valid & res.accept
             k_n = res.num_accepted
             path = res.path_nodes
-            keeps = ver.tree_consensus_keep(acc_mats, path, k_n, run)
-            for j in range(N):
-                keep = kvc.path_keep_matrix(path, keeps[j], NT, D)
-                states[j] = kvc.resolve_tree(states[j], NT, keep, keeps[j],
-                                             active=run)
-            path_tokens = jnp.take_along_axis(cand, path, axis=1)
-            seq, new_len, slab, cnum = _commit_dev(
-                seq, sl32, run, path_tokens, k_n, res.next_token, C)
-            new_seq_len, new_active = _terminate_dev(
-                slab, run, sl32, new_len,
-                prompt_len.astype(jnp.int32), budget.astype(jnp.int32),
-                active, eos)
-            lengths, wps, fts, nbs = _state_summary(states)
+            with jax.named_scope("rollback"):
+                keeps = ver.tree_consensus_keep(acc_mats, path, k_n, run)
+                for j in range(N):
+                    keep = kvc.path_keep_matrix(path, keeps[j], NT, D)
+                    states[j] = kvc.resolve_tree(states[j], NT, keep,
+                                                 keeps[j], active=run)
+            with jax.named_scope("commit"):
+                path_tokens = jnp.take_along_axis(cand, path, axis=1)
+                seq, new_len, slab, cnum = _commit_dev(
+                    seq, sl32, run, path_tokens, k_n, res.next_token, C)
+                new_seq_len, new_active = _terminate_dev(
+                    slab, run, sl32, new_len,
+                    prompt_len.astype(jnp.int32), budget.astype(jnp.int32),
+                    active, eos)
+                lengths, wps, fts, nbs = _state_summary(states)
             summary = FusedSummary(slab, cnum, new_seq_len, new_active,
                                    jnp.stack(ks).astype(jnp.int32),
                                    jnp.stack(dts).astype(jnp.float32),
@@ -960,6 +982,12 @@ class Executor:
             body = self._build_fused_linear(lms, window, greedy,
                                             temperature, prefix_width, eos,
                                             reshard=reshard)
+        # the program's name carries its key, so a compile log or a trace
+        # says which (levels, window or tree, prefix width) it is
+        shape = (f"t{'x'.join(map(str, tkey))}" if tree is not None
+                 else f"w{window}")
+        body.__name__ = body.__qualname__ = \
+            f"fused_{len(chain)}L_{shape}_p{prefix_width}"
         # donate the model states + the seq/seq_len/active session buffers:
         # the cycle replaces them wholesale, so XLA can update in place
         prog = jax.jit(body, donate_argnums=(1, 2, 3, 6))
@@ -972,45 +1000,47 @@ class Executor:
         session buffers donated) → commit; exactly ONE host sync — the
         ``FusedSummary`` device_get — per call.  Returns
         ({seq, seq_len, active} new device buffers, numpy FusedSummary)."""
-        sids = [StateManager.key(m, req.request_id) for m in req.chain]
-        params = tuple(self.pool.params(m) for m in req.chain)
-        prog = self._fused_program(req.chain, req.window, req.tree,
-                                   req.greedy, req.temperature,
-                                   req.prefix_width, req.eos)
-        states = self.states.checkout(sids)
-        t0 = time.perf_counter()
-        ok = False
-        try:
-            with self._mctx():
-                out = prog(params, tuple(states), req.seq, req.seq_len,
-                           req.prompt_len, req.budget, req.active,
-                           req.gmask, tuple(req.rngs))
-            ok = True
-        finally:
-            # try/finally, not a broad except: nothing is swallowed and
-            # the cleanup also covers KeyboardInterrupt/SystemExit.
-            # Trace-time failure: nothing executed, buffers still valid —
-            # restore them.  A RUNTIME failure after dispatch (e.g. device
-            # OOM) has already consumed the donated buffers; committing
-            # deleted arrays would poison every later op with confusing
-            # "Array has been deleted" errors, so drop the registry
-            # entries instead and let the next access fail cleanly.
-            if not ok:
-                donated = any(
-                    getattr(leaf, "is_deleted", lambda: False)()
-                    for st in states for leaf in jax.tree.leaves(st))
-                if donated:
-                    for sid in sids:
-                        self.states.release(sid)
-                else:
-                    self.states.commit(sids, states)
-        new_states, seq, seq_len, active, summary = out
-        self.states.commit(sids, list(new_states))
-        # speclint: disable=host-sync -- THE sanctioned one-transfer-per-
-        # cycle FusedSummary device_get (PR 5 contract; counted below)
-        summary = jax.device_get(summary)
+        with self.profiler.span("cycle.dispatch", chain="+".join(req.chain)):
+            sids = [StateManager.key(m, req.request_id) for m in req.chain]
+            params = tuple(self.pool.params(m) for m in req.chain)
+            prog = self._fused_program(req.chain, req.window, req.tree,
+                                       req.greedy, req.temperature,
+                                       req.prefix_width, req.eos)
+            states = self.states.checkout(sids)
+            t0 = time.perf_counter()
+            ok = False
+            try:
+                with self._mctx():
+                    out = prog(params, tuple(states), req.seq, req.seq_len,
+                               req.prompt_len, req.budget, req.active,
+                               req.gmask, tuple(req.rngs))
+                ok = True
+            finally:
+                # try/finally, not a broad except: nothing is swallowed and
+                # the cleanup also covers KeyboardInterrupt/SystemExit.
+                # Trace-time failure: nothing executed, buffers still valid
+                # — restore them.  A RUNTIME failure after dispatch (e.g.
+                # device OOM) has already consumed the donated buffers;
+                # committing deleted arrays would poison every later op
+                # with confusing "Array has been deleted" errors, so drop
+                # the registry entries instead and let the next access
+                # fail cleanly.
+                if not ok:
+                    donated = any(
+                        getattr(leaf, "is_deleted", lambda: False)()
+                        for st in states for leaf in jax.tree.leaves(st))
+                    if donated:
+                        for sid in sids:
+                            self.states.release(sid)
+                    else:
+                        self.states.commit(sids, states)
+            new_states, seq, seq_len, active, summary = out
+            self.states.commit(sids, list(new_states))
+        with self.profiler.wait():
+            # speclint: disable=host-sync -- THE sanctioned one-transfer-
+            # per-cycle FusedSummary device_get (counted by profiler.wait)
+            summary = jax.device_get(summary)
         dt = time.perf_counter() - t0
-        self.profiler.count("host_sync")
         self.profiler.record("fused_cycle",
                              "+".join(self._pq(m) for m in req.chain), dt,
                              tokens=int(summary.n_committed.sum()))
@@ -1033,6 +1063,6 @@ class Executor:
             state = self._resolve_tree(req.model, req.tree)(
                 state, jnp.asarray(req.path_nodes, jnp.int32),
                 jnp.asarray(req.keep_len, jnp.int32), active)
-            jax.block_until_ready(state.write_ptr)
-        self.profiler.count("host_sync")
+            with self.profiler.wait():
+                jax.block_until_ready(state.write_ptr)
         self.states.update(sid, state)

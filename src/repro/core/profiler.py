@@ -1,14 +1,19 @@
 """PerformanceProfiler (paper §4.6): low-overhead wall-time + counter
 metrics, EMA-smoothed (paper §4.2 input metrics), feeding the
-ModelChainScheduler's adaptive loop.
+ModelChainScheduler's adaptive loop, plus the host spans of the serving
+path (``span``/``wait``), which land in a ``jax.profiler`` trace on the
+device ops' clock and in an in-memory table.
 """
 from __future__ import annotations
 
 import collections
 import contextlib
 import time
-from dataclasses import dataclass, field
-from typing import Dict, Optional
+from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+_now = time.perf_counter
 
 
 class EMA:
@@ -29,17 +34,43 @@ class EMA:
         return default if self.value is None else self.value
 
 
-@dataclass
-class OpRecord:
-    op: str
-    model: str
-    wall_s: float
-    tokens: int
-    meta: dict = field(default_factory=dict)
+class _Span:
+    """One open host span: a ``TraceAnnotation`` plus its row of the
+    profiler's span table (count, seconds, self seconds)."""
+
+    __slots__ = ("prof", "name", "ann", "t0")
+
+    def __init__(self, prof: "PerformanceProfiler", name: str, args: dict):
+        self.prof = prof
+        self.name = name
+        self.ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self.prof._open.append(0.0)
+        self.ann.__enter__()
+        self.t0 = _now()
+        return self
+
+    def __exit__(self, etype, value, tb):
+        dt = _now() - self.t0
+        self.ann.__exit__(etype, value, tb)
+        prof = self.prof
+        stack = prof._open
+        child = stack.pop()
+        if stack:
+            stack[-1] += dt
+        row = prof.spans.get(self.name)
+        if row is None:
+            prof.spans[self.name] = [1, dt, dt - child]
+        else:
+            row[0] += 1
+            row[1] += dt
+            row[2] += dt - child
+        return False
 
 
 class PerformanceProfiler:
-    """Gathers (op, model) -> EMA wall time; plus counters and a trace.
+    """Gathers (op, model) -> EMA wall time; plus counters and spans.
 
     Keys used by the scheduler:
       ("decode1", m)        — per-token single-step decode time T_i
@@ -63,30 +94,46 @@ class PerformanceProfiler:
                               the scheduler's Eq. 7 inputs snapshot — the
                               LoadSignal carries it instead
 
-    The ``host_sync`` counter tallies host-synchronizing op dispatches
-    (device→host transfers that block on the device): one per per-op
+    The ``host_sync`` counter tallies host-synchronizing waits on the
+    device, each inside a ``cycle.wait`` span (``wait()``): one per per-op
     processor call on the legacy path, ONE per cycle group on the fused
     path — ``benchmarks/cycle_overhead.py`` asserts the gap.
+
+    Spans (``span(name, **args)``) have fixed names; what varies (model,
+    chain, request id) goes in ``args``, which reach the trace as the
+    event's stats.  ``spans[name]`` is ``[count, seconds, self seconds]``,
+    self seconds being the span's time less that of the spans opened
+    inside it.  With no ``jax.profiler`` session active a span costs a
+    few microseconds of host time, so spans are always on.
     """
 
-    def __init__(self, alpha: float = 0.3, keep_trace: bool = True,
-                 trace_cap: Optional[int] = 4096):
+    def __init__(self, alpha: float = 0.3):
         self.alpha = alpha
         self.emas: Dict[tuple, EMA] = collections.defaultdict(
             lambda: EMA(self.alpha))
         self.counters: Dict[str, float] = collections.defaultdict(float)
-        # bounded ring buffer: a long-running serving session records an
-        # OpRecord per op forever, so an unbounded list is a memory leak —
-        # keep the most recent ``trace_cap`` records (None = unbounded,
-        # for short offline analyses that want the full trace)
-        self.trace: collections.deque = collections.deque(maxlen=trace_cap)
-        self.keep_trace = keep_trace
+        self.spans: Dict[str, List[float]] = {}
+        self._open: List[float] = []     # children seconds per open span
+
+    def span(self, name: str, **args) -> _Span:
+        """Context manager: a host span ``name`` in the trace and in
+        ``spans``."""
+        return _Span(self, name, args)
+
+    def wait(self) -> _Span:
+        """Context manager around a blocking wait on the device: a
+        ``cycle.wait`` span that also counts one ``host_sync``."""
+        self.counters["host_sync"] += 1
+        return _Span(self, "cycle.wait", {})
 
     @contextlib.contextmanager
     def timed(self, op: str, model: str, tokens: int = 1, **meta):
-        t0 = time.perf_counter()
-        yield
-        dt = time.perf_counter() - t0
+        """An ``op.<op>`` span whose wall time also feeds the (op, model)
+        EMA."""
+        with self.span(f"op.{op}", model=model):
+            t0 = time.perf_counter()
+            yield
+            dt = time.perf_counter() - t0
         self.record(op, model, dt, tokens, **meta)
 
     def record(self, op: str, model: str, wall_s: float, tokens: int = 1,
@@ -95,8 +142,6 @@ class PerformanceProfiler:
         self.emas[key].update(wall_s)
         self.counters[f"{op}.{model}.calls"] += 1
         self.counters[f"{op}.{model}.tokens"] += tokens
-        if self.keep_trace:
-            self.trace.append(OpRecord(op, model, wall_s, tokens, meta))
 
     def count(self, name: str, inc: float = 1.0):
         self.counters[name] += inc
@@ -136,11 +181,3 @@ class PerformanceProfiler:
         long a queued request waits per cycle boundary (SLO-aware
         scheduling and the admission shed policy both read it)."""
         return self.emas[("cycle_wall", "session")].get(default)
-
-    def summary(self) -> Dict[str, float]:
-        out = {}
-        for k, e in self.emas.items():
-            if e.count:
-                out["/".join(map(str, k))] = e.get()
-        out.update(self.counters)
-        return out

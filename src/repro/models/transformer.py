@@ -186,24 +186,30 @@ def _block(pl, cfg: ModelConfig, x, *, k_cached, v_cached, mask,
 
     if k_cached is not None and paged_idx is not None:
         phys_new, view_idx = paged_idx
+        # device scopes: the pool write and the per-row gathered view are
+        # the paged cache's own traffic in a profile
         if cfg.kv_quant:
             kq, ksc = kvc.kv_quantize(k_new)
             vq, vsc = kvc.kv_quantize(v_new)
-            ck, cv = kvc.paged_write_kv(k_cached, v_cached, kq, vq, phys_new)
-            cks = kvc.paged_scatter(kv_scales[0], ksc, phys_new)
-            cvs = kvc.paged_scatter(kv_scales[1], vsc, phys_new)
-            attn_out = nn.gqa_attention_quant(
-                q, kvc.paged_gather(ck, view_idx),
-                kvc.paged_gather(cks, view_idx),
-                kvc.paged_gather(cv, view_idx),
-                kvc.paged_gather(cvs, view_idx), mask, cfg.attn_softcap)
+            with jax.named_scope("kv_write"):
+                ck, cv = kvc.paged_write_kv(k_cached, v_cached, kq, vq,
+                                            phys_new)
+                cks = kvc.paged_scatter(kv_scales[0], ksc, phys_new)
+                cvs = kvc.paged_scatter(kv_scales[1], vsc, phys_new)
+            with jax.named_scope("kv_gather"):
+                views = [kvc.paged_gather(c, view_idx)
+                         for c in (ck, cks, cv, cvs)]
+            attn_out = nn.gqa_attention_quant(q, *views, mask,
+                                              cfg.attn_softcap)
             new_cache = (ck, cv, cks, cvs)
         else:
-            ck, cv = kvc.paged_write_kv(k_cached, v_cached, k_new, v_new,
-                                        phys_new)
-            attn_out = nn.gqa_attention(q, kvc.paged_gather(ck, view_idx),
-                                        kvc.paged_gather(cv, view_idx),
-                                        mask, cfg.attn_softcap)
+            with jax.named_scope("kv_write"):
+                ck, cv = kvc.paged_write_kv(k_cached, v_cached, k_new, v_new,
+                                            phys_new)
+            with jax.named_scope("kv_gather"):
+                kv, vv = (kvc.paged_gather(ck, view_idx),
+                          kvc.paged_gather(cv, view_idx))
+            attn_out = nn.gqa_attention(q, kv, vv, mask, cfg.attn_softcap)
             new_cache = (ck, cv)
     elif k_cached is not None:
         if cfg.kv_quant:

@@ -62,14 +62,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..core import (ChainRouter, LoadSignal, ModelPool, PerformanceProfiler,
-                    Placement)
+from ..core import ChainRouter, LoadSignal, ModelPool, Placement
 from ..data.workload import Request
-
-# serving keeps a bounded op trace: the profiler's EMAs/counters (what the
-# scheduler reads) are O(1), but OpRecords accumulate per op — a small ring
-# is plenty for debugging and cannot leak over a long-running engine
-_SERVING_TRACE_CAP = 512
 
 
 @dataclasses.dataclass
@@ -162,8 +156,6 @@ class ServingEngine:
             self.router_kwargs.setdefault("paged", paged)
         if fused is not None:              # device-resident cycles A/B
             self.router_kwargs.setdefault("fused", fused)
-        self.router_kwargs.setdefault(
-            "profiler", PerformanceProfiler(trace_cap=_SERVING_TRACE_CAP))
         # one router per engine: jit caches and scheduler state persist
         # across batches (recompiling per batch would bill compilation to
         # every request's latency)
@@ -242,6 +234,7 @@ class ServingEngine:
         while cap < max_len:
             cap *= 2
         sess = router.start_session(B, cap, session_id="serve")
+        prof = router.profiler
 
         slot_req: List[Optional[Request]] = [None] * B
         clock = 0.0
@@ -254,47 +247,9 @@ class ServingEngine:
         cycles = 0
         while (i < len(reqs) or queue
                or any(r is not None for r in slot_req)):
-            busy = any(r is not None for r in slot_req)
-            if not busy and not queue and reqs[i].arrival_s > clock:
-                clock = reqs[i].arrival_s          # idle: jump to arrival
-            # run-queue refill: every arrival up to the current clock
-            while i < len(reqs) and reqs[i].arrival_s <= clock:
-                queue.append(reqs[i])
-                i += 1
-            # shed policy: a queued request whose TTFT deadline is already
-            # unmeetable — it cannot commit a first token before at least
-            # one more cycle elapses (cycle-latency EMA) — is dropped NOW,
-            # so slot capacity goes to requests that can still meet SLO
-            if self.shed_policy == "ttft" and queue:
-                est = self._router.profiler.cycle_time()
-                kept = []
-                for q in queue:
-                    if clock + est >= q.ttft_deadline_s:
-                        q.shed = True
-                    else:
-                        kept.append(q)
-                queue = kept
-            # SLO-aware admission order: earliest TTFT deadline first.
-            # Requests without a TTFT SLO have an infinite deadline, and
-            # the arrival-time tie-break keeps them (and whole no-SLO
-            # populations) in exact FIFO order — today's behaviour.
-            queue.sort(key=lambda q: (q.ttft_deadline_s, q.arrival_s))
-            for s in range(B):
-                if slot_req[s] is None and queue:
-                    r = queue.pop(0)
-                    r.start_s = clock   # queueing ends, service begins
-                    clock += sess.admit(s, r.prompt, r.max_new_tokens,
-                                        ttft_slo_s=r.ttft_slo_s,
-                                        tpot_slo_s=r.tpot_slo_s)
-                    slot_req[s] = r
-            # publish the load signal the goodput-aware chain search
-            # reads: residual run-queue depth, slot occupancy, and the
-            # profiler's cycle-latency EMA
-            busy_n = sum(r is not None for r in slot_req)
-            self._router.scheduler.set_load(LoadSignal(
-                queue_depth=len(queue), occupancy=busy_n / B,
-                cycle_ema_s=self._router.profiler.cycle_time(),
-                num_slots=B))
+            with prof.span("serve.queue"):
+                clock, i, queue = self._refill_and_admit(
+                    sess, reqs, slot_req, queue, clock, i)
             rep = sess.run_cycle()
             clock += rep.wall_s
             cycles += 1
@@ -302,17 +257,20 @@ class ServingEngine:
                 self._fused_syncs.append(rep.host_syncs)
             if rep.commits.any():
                 acc_lens.append(rep.acc_mean)
-            for s in range(B):
-                r = slot_req[s]
-                if r is None:
-                    continue
-                if rep.commits[s] > 0 and r.first_token_s < 0:
-                    r.first_token_s = clock
-                if not sess.active[s]:
-                    r.finish_s = clock
-                    r.output_tokens = sess.retire(s)
-                    r.generated = len(r.output_tokens)
-                    slot_req[s] = None
+            with prof.span("serve.collect"):
+                for s in range(B):
+                    r = slot_req[s]
+                    if r is None:
+                        continue
+                    if rep.commits[s] > 0 and r.first_token_s < 0:
+                        r.first_token_s = clock
+                    if not sess.active[s]:
+                        r.finish_s = clock
+                        with prof.span("serve.retire",
+                                       request_id=r.request_id):
+                            r.output_tokens = sess.retire(s)
+                        r.generated = len(r.output_tokens)
+                        slot_req[s] = None
             if cycles > cycle_cap:
                 raise RuntimeError("continuous engine exceeded cycle cap "
                                    "(stuck slot?)")
@@ -321,6 +279,58 @@ class ServingEngine:
         # scheduler user) must not inherit a stale pressure reading
         self._router.scheduler.set_load(None)
         return acc_lens
+
+    def _refill_and_admit(self, sess, reqs: List[Request],
+                          slot_req: List[Optional[Request]],
+                          queue: List[Request], clock: float, i: int):
+        """Between two cycles: move arrivals into the run queue, shed,
+        order it, admit into free slots and publish the load signal.
+        Returns the new (clock, next arrival index, queue)."""
+        B = self.batch_size
+        busy = any(r is not None for r in slot_req)
+        if not busy and not queue and reqs[i].arrival_s > clock:
+            clock = reqs[i].arrival_s          # idle: jump to arrival
+        # run-queue refill: every arrival up to the current clock
+        while i < len(reqs) and reqs[i].arrival_s <= clock:
+            queue.append(reqs[i])
+            i += 1
+        # shed policy: a queued request whose TTFT deadline is already
+        # unmeetable — it cannot commit a first token before at least
+        # one more cycle elapses (cycle-latency EMA) — is dropped NOW,
+        # so slot capacity goes to requests that can still meet SLO
+        if self.shed_policy == "ttft" and queue:
+            est = self._router.profiler.cycle_time()
+            kept = []
+            for q in queue:
+                if clock + est >= q.ttft_deadline_s:
+                    q.shed = True
+                else:
+                    kept.append(q)
+            queue = kept
+        # SLO-aware admission order: earliest TTFT deadline first.
+        # Requests without a TTFT SLO have an infinite deadline, and
+        # the arrival-time tie-break keeps them (and whole no-SLO
+        # populations) in exact FIFO order — today's behaviour.
+        queue.sort(key=lambda q: (q.ttft_deadline_s, q.arrival_s))
+        for s in range(B):
+            if slot_req[s] is None and queue:
+                r = queue.pop(0)
+                r.start_s = clock   # queueing ends, service begins
+                with self._router.profiler.span("serve.admit",
+                                                request_id=r.request_id):
+                    clock += sess.admit(s, r.prompt, r.max_new_tokens,
+                                        ttft_slo_s=r.ttft_slo_s,
+                                        tpot_slo_s=r.tpot_slo_s)
+                slot_req[s] = r
+        # publish the load signal the goodput-aware chain search
+        # reads: residual run-queue depth, slot occupancy, and the
+        # profiler's cycle-latency EMA
+        busy_n = sum(r is not None for r in slot_req)
+        self._router.scheduler.set_load(LoadSignal(
+            queue_depth=len(queue), occupancy=busy_n / B,
+            cycle_ema_s=self._router.profiler.cycle_time(),
+            num_slots=B))
+        return clock, i, queue
 
     # ------------------------------------------------------------------
     # legacy mode: stop-the-world batch formation (A/B baseline)

@@ -1,8 +1,8 @@
 """Per-slot chain routing with lazy chain membership: O(chain) admission
 (pinned prefill/insert counters, zero footprint in non-chain models),
 bit-exact grouped sub-cycles for slots on different chains, clean
-rejection of over-long prompts, the vectorized gap-prefix fast path, and
-the profiler's bounded trace ring."""
+rejection of over-long prompts, and the vectorized gap-prefix fast
+path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -290,30 +290,3 @@ def test_unobserved_pairs_use_exploration_default():
     for _ in range(8):
         sched.sims.update("d", "t", 0.99)
     assert sched.get_optimal_chain().chain == ("t",)
-
-
-# ---------------------------------------------------------------------------
-# profiler trace ring (satellite bugfix)
-# ---------------------------------------------------------------------------
-def test_profiler_trace_is_bounded():
-    prof = PerformanceProfiler(trace_cap=16)
-    for i in range(100):
-        prof.record("decode1", "m", 0.001 * i)
-    assert len(prof.trace) == 16
-    # the ring keeps the MOST RECENT records
-    assert prof.trace[-1].wall_s == pytest.approx(0.099)
-    assert prof.trace[0].wall_s == pytest.approx(0.084)
-    # EMAs/counters still see every observation
-    assert prof.counters["decode1.m.calls"] == 100
-    # unbounded opt-in for offline analyses
-    prof2 = PerformanceProfiler(trace_cap=None)
-    for i in range(100):
-        prof2.record("decode1", "m", 0.001)
-    assert len(prof2.trace) == 100
-
-
-def test_serving_engine_defaults_to_bounded_trace(pool):
-    from repro.serving import ServingEngine
-    eng = ServingEngine(pool, "t")
-    assert eng._router.profiler.trace.maxlen is not None
-    assert eng._router.profiler.trace.maxlen <= 4096
