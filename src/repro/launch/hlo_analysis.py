@@ -95,6 +95,25 @@ def parse_hlo(text: str):
     return comps
 
 
+def copies_of(text: str, shapes) -> List[str]:
+    """``computation/name: result`` of every copy in optimized HLO — a
+    ``copy`` or ``copy-start``, or a fusion XLA names ``copy*`` — whose
+    result holds an array of one of ``shapes`` (dimensions only; dtype
+    and layout aside)."""
+    wanted = {tuple(s) for s in shapes}
+    hits = []
+    for comp, instrs in parse_hlo(text).items():
+        for ins in instrs:
+            if not (ins.op in ("copy", "copy-start") or (
+                    ins.op == "fusion" and ins.name.startswith("copy"))):
+                continue
+            dims = {tuple(int(d) for d in ds.split(",") if d)
+                    for _, ds in _SHAPE_RE.findall(ins.out_text)}
+            if dims & wanted:
+                hits.append(f"{comp}/{ins.name}: {ins.out_text.strip()}")
+    return hits
+
+
 def _multipliers(comps) -> Tuple[Dict[str, int], set]:
     """Propagate loop trip counts through the call graph.
 

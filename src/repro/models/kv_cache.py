@@ -375,6 +375,21 @@ def write_kv(cache_k: jnp.ndarray, cache_v: jnp.ndarray,
     return ck, cv
 
 
+def write_layer(cache: jnp.ndarray, layer, new: jnp.ndarray, slot_start):
+    """Write (B, T, Hkv, ...) entries into layer ``layer`` of a stacked
+    (L, B, S, Hkv, ...) cache at the slots ``write_kv`` writes (its
+    ``dynamic_update_slice`` clamps the start so the block fits) — in
+    place when ``cache`` is a scan carry.  A scatter of (row, slot, head)
+    entries, like ``paged_scatter_layer``: a block update would make the
+    TPU copy the cache out of its head-major layout and back, and the CPU
+    widen a bf16 cache to f32 around the whole stack."""
+    B, T, H = new.shape[:3]
+    start = jnp.clip(slot_start, 0, cache.shape[2] - T)
+    return cache.at[layer, jnp.arange(B)[:, None, None],
+                    (start + jnp.arange(T))[None, :, None],
+                    jnp.arange(H)].set(new.astype(cache.dtype))
+
+
 # ---------------------------------------------------------------------------
 # SSM snapshot buffers (rollback support for recurrent archs — DESIGN §5)
 # ---------------------------------------------------------------------------
@@ -620,6 +635,24 @@ def paged_gather(cache_flat: jnp.ndarray,
                  view_idx: jnp.ndarray) -> jnp.ndarray:
     """(P·bs, ...) pool cache -> (B, S, ...) per-row contiguous view."""
     return cache_flat[view_idx]
+
+
+def paged_scatter_layer(cache: jnp.ndarray, layer, new: jnp.ndarray,
+                        phys: jnp.ndarray) -> jnp.ndarray:
+    """``paged_scatter`` into layer ``layer`` of a stacked (L, P·bs, Hkv,
+    ...) pool — in place when ``cache`` is a scan carry.
+
+    Each (slot, head) entry is its own update, so only the trailing
+    head_dim is a contiguous window: a whole (Hkv, hd) row is not, in the
+    head-major layout the TPU gives a pool whose Hkv is not a multiple of
+    8, and a row-window scatter would make XLA copy the pool into
+    row-major order and back around the layer scan.  (Reads stay row
+    gathers from the layer's slice, ``paged_gather(cache[layer], ...)``:
+    a gather of single (slot, head) entries is too fine for the TPU.)"""
+    flat = new.reshape((-1,) + new.shape[2:]).astype(cache.dtype)
+    heads = jnp.arange(cache.shape[2])
+    return cache.at[layer, phys.reshape(-1)[:, None], heads].set(
+        flat, mode="drop")
 
 
 def paged_write_kv(cache_k, cache_v, k_new, v_new, phys):

@@ -7,7 +7,8 @@ logit softcap), qwen1.5 (QKV bias), minitron, granite (MQA), whisper decoder
 Layers are stacked along a leading L axis and executed with ``lax.scan``
 (compile-time O(1) in depth — essential for 62-layer dry-runs on this host).
 Per-layer heterogeneity (local vs global attention, rope theta) rides along
-as scanned flag arrays.
+as scanned flag arrays.  The stacked KV cache is the scan's carry: each
+layer writes into, and reads from, its own layer of it in place.
 """
 from __future__ import annotations
 
@@ -153,25 +154,15 @@ def _qk_normed(pl, cfg, q, k):
     return q, k
 
 
-def _block(pl, cfg: ModelConfig, x, *, k_cached, v_cached, mask,
-           q_pos3, theta, cross_kv=None, write_slot=None, kv_scales=None,
-           paged_idx=None):
+def _block(pl, cfg: ModelConfig, x, *, attend, q_pos3, theta,
+           cross_kv=None):
     """One transformer block.
 
-    k_cached/v_cached: (B, S, Hkv, hd) — full physical cache view for this
-    layer (already containing the new tokens' K/V written by caller? No —
-    we compute and write here when write_slot is given; for trainer mode
-    k_cached is None and attention is over the block itself).
-    kv_scales: (k_scale, v_scale) (B, S, Hkv) when cfg.kv_quant.
-    paged_idx: (phys_new (B, T), view_idx (B, S)) when the state is paged —
-    k_cached/v_cached are then flat pool tensors (P·bs, Hkv, hd): new K/V
-    scatter to ``phys_new`` and attention consumes the per-row gathered
-    view.  Materializing the gather is the CPU/jnp staging path (same
-    convention as every kernel in this repo: the jnp forward is the
-    oracle-checked reference); the TPU serving path replaces it with
-    ``ops.paged_decode_attention``, whose scalar-prefetched block table
-    performs the identical gather block-by-block inside the kernel
-    pipeline with no materialized view.
+    ``attend(q, k_new, v_new) -> (attn_out, kv)`` is the block's
+    self-attention.  The trainer attends within the block and returns no
+    cache; the cached forward (``_cached_attention``) writes the block's
+    new K/V into its layer of the stacked cache, attends over that layer,
+    and returns the updated stack.  Returns ``(x, kv)``.
     """
     h = nn.rmsnorm(pl["ln1"], x, cfg.rms_eps)
     q, k_new, v_new = nn.attention_qkv(pl["attn"], h, cfg)
@@ -184,53 +175,7 @@ def _block(pl, cfg: ModelConfig, x, *, k_cached, v_cached, mask,
         q = _rope_traced(q, qp, theta, cfg.head_dim)
         k_new = _rope_traced(k_new, qp, theta, cfg.head_dim)
 
-    if k_cached is not None and paged_idx is not None:
-        phys_new, view_idx = paged_idx
-        # device scopes: the pool write and the per-row gathered view are
-        # the paged cache's own traffic in a profile
-        if cfg.kv_quant:
-            kq, ksc = kvc.kv_quantize(k_new)
-            vq, vsc = kvc.kv_quantize(v_new)
-            with jax.named_scope("kv_write"):
-                ck, cv = kvc.paged_write_kv(k_cached, v_cached, kq, vq,
-                                            phys_new)
-                cks = kvc.paged_scatter(kv_scales[0], ksc, phys_new)
-                cvs = kvc.paged_scatter(kv_scales[1], vsc, phys_new)
-            with jax.named_scope("kv_gather"):
-                views = [kvc.paged_gather(c, view_idx)
-                         for c in (ck, cks, cv, cvs)]
-            attn_out = nn.gqa_attention_quant(q, *views, mask,
-                                              cfg.attn_softcap)
-            new_cache = (ck, cv, cks, cvs)
-        else:
-            with jax.named_scope("kv_write"):
-                ck, cv = kvc.paged_write_kv(k_cached, v_cached, k_new, v_new,
-                                            phys_new)
-            with jax.named_scope("kv_gather"):
-                kv, vv = (kvc.paged_gather(ck, view_idx),
-                          kvc.paged_gather(cv, view_idx))
-            attn_out = nn.gqa_attention(q, kv, vv, mask, cfg.attn_softcap)
-            new_cache = (ck, cv)
-    elif k_cached is not None:
-        if cfg.kv_quant:
-            kq, ksc = kvc.kv_quantize(k_new)
-            vq, vsc = kvc.kv_quantize(v_new)
-            ck, cv = kvc.write_kv(k_cached, v_cached, kq, vq, write_slot)
-            upd = lambda buf, new: jax.lax.dynamic_update_slice_in_dim(
-                buf, new.astype(buf.dtype), write_slot, axis=1)
-            cks = upd(kv_scales[0], ksc)
-            cvs = upd(kv_scales[1], vsc)
-            attn_out = nn.gqa_attention_quant(
-                q, ck, cks, cv, cvs, mask, cfg.attn_softcap)
-            new_cache = (ck, cv, cks, cvs)
-        else:
-            ck, cv = kvc.write_kv(k_cached, v_cached, k_new, v_new,
-                                  write_slot)
-            attn_out = nn.gqa_attention(q, ck, cv, mask, cfg.attn_softcap)
-            new_cache = (ck, cv)
-    else:
-        attn_out = nn.gqa_attention(q, k_new, v_new, mask, cfg.attn_softcap)
-        new_cache = None
+    attn_out, kv = attend(q, k_new, v_new)
     a = nn.attention_out(pl["attn"], attn_out)
     if cfg.sandwich_norm:
         a = nn.rmsnorm(pl["post_attn_ln"], a, cfg.rms_eps)
@@ -250,7 +195,48 @@ def _block(pl, cfg: ModelConfig, x, *, k_cached, v_cached, mask,
     m = nn.swiglu(pl["mlp"], h2)
     if cfg.sandwich_norm:
         m = nn.rmsnorm(pl["post_mlp_ln"], m, cfg.rms_eps)
-    return x + m, new_cache
+    return x + m, kv
+
+
+def _cached_attention(cfg: ModelConfig, kv, layer, q, k_new, v_new, *,
+                      mask, write_slot=None, paged_idx=None):
+    """Write layer ``layer``'s new K/V into the stacked cache ``kv``
+    ({"k", "v"}, plus {"k_scale", "v_scale"} under ``kv_quant``; the layer
+    scan's carry, so every write lands in place) and attend over that layer.
+
+    Paged (``paged_idx`` = (phys_new (B, T), view_idx (B, S))): the arrays
+    are flat pools (L, P·bs, ...); new entries scatter to ``phys_new`` and
+    attention reads the per-row view ``view_idx`` from the layer's slice of
+    the pool after the write.  Contiguous: (L, B, S, ...), written at
+    ``write_slot``.
+    Returns ``(attn_out, kv)``.
+    """
+    new = {"k": k_new, "v": v_new}
+    if cfg.kv_quant:
+        new["k"], new["k_scale"] = kvc.kv_quantize(k_new)
+        new["v"], new["v_scale"] = kvc.kv_quantize(v_new)
+    if paged_idx is not None:
+        phys_new, view_idx = paged_idx
+        # device scopes: the pool write and the per-row gathered view are
+        # the paged cache's own traffic in a profile
+        with jax.named_scope("kv_write"):
+            kv = {n: kvc.paged_scatter_layer(c, layer, new[n], phys_new)
+                  for n, c in kv.items()}
+        with jax.named_scope("kv_gather"):
+            view = {n: kvc.paged_gather(c[layer], view_idx)
+                    for n, c in kv.items()}
+    else:
+        kv = {n: kvc.write_layer(c, layer, new[n], write_slot)
+              for n, c in kv.items()}
+        view = {n: c[layer] for n, c in kv.items()}
+    if cfg.kv_quant:
+        out = nn.gqa_attention_quant(q, view["k"], view["k_scale"],
+                                     view["v"], view["v_scale"], mask,
+                                     cfg.attn_softcap)
+    else:
+        out = nn.gqa_attention(q, view["k"], view["v"], mask,
+                               cfg.attn_softcap)
+    return out, kv
 
 
 def _rope_traced(x, positions, theta, head_dim):
@@ -382,32 +368,31 @@ def forward_cached(params, cfg: ModelConfig, state: kvc.ModelState,
 
     is_global, thetas = layer_flags(cfg)
     has_cross = cfg.encdec is not None
-    xs = {"pl": params["blocks"], "ck": state.layers["k"],
-          "cv": state.layers["v"], "g": is_global, "theta": thetas}
-    if cfg.kv_quant:
-        xs["cks"] = state.layers["k_scale"]
-        xs["cvs"] = state.layers["v_scale"]
+    # what each layer writes rides in the carry and is updated in place;
+    # only what layers read is scanned over
+    kv_names = ("k", "v", "k_scale", "v_scale") if cfg.kv_quant \
+        else ("k", "v")
+    kv = {n: state.layers[n] for n in kv_names}
+    xs = {"pl": params["blocks"], "g": is_global, "theta": thetas,
+          "layer": jnp.arange(cfg.num_layers, dtype=jnp.int32)}
     if has_cross:
         xs["xk"] = state.layers["cross_k"]
         xs["xv"] = state.layers["cross_v"]
 
-    def body(x, s):
+    def body(carry, s):
+        x, kv = carry
         mask = jnp.where(s["g"], m_full, m_win) if cfg.sliding_window > 0 \
             else m_full
         cross = (s["xk"], s["xv"]) if has_cross else None
-        scales = (s["cks"], s["cvs"]) if cfg.kv_quant else None
-        x, caches = _block(
-            s["pl"], cfg, x, k_cached=s["ck"], v_cached=s["cv"], mask=mask,
-            q_pos3=q_pos3, theta=s["theta"], cross_kv=cross,
-            write_slot=None if paged else slot, kv_scales=scales,
-            paged_idx=paged_idx)
-        out = {"k": caches[0], "v": caches[1]}
-        if cfg.kv_quant:
-            out["k_scale"], out["v_scale"] = caches[2], caches[3]
-        return x, out
+        attend = partial(_cached_attention, cfg, kv, s["layer"], mask=mask,
+                         write_slot=None if paged else slot,
+                         paged_idx=paged_idx)
+        x, kv = _block(s["pl"], cfg, x, attend=attend, q_pos3=q_pos3,
+                       theta=s["theta"], cross_kv=cross)
+        return (x, kv), None
 
-    x, new_kv = jax.lax.scan(body, x, xs)
-    state = dataclasses.replace(state, layers={**state.layers, **new_kv})
+    (x, kv), _ = jax.lax.scan(body, (x, kv), xs)
+    state = dataclasses.replace(state, layers={**state.layers, **kv})
 
     if logits_mode == "none":
         return None, state
@@ -457,9 +442,10 @@ def forward_train(params, cfg: ModelConfig, tokens: jnp.ndarray,
         mask = jnp.where(s["g"], m_full, m_win) if cfg.sliding_window > 0 \
             else m_full
         cross = (s["xk"], s["xv"]) if has_cross else None
-        x, _ = _block(s["pl"], cfg, x, k_cached=None, v_cached=None,
-                      mask=mask, q_pos3=q_pos3, theta=s["theta"],
-                      cross_kv=cross)
+        attend = lambda q, k, v: (
+            nn.gqa_attention(q, k, v, mask, cfg.attn_softcap), None)
+        x, _ = _block(s["pl"], cfg, x, attend=attend, q_pos3=q_pos3,
+                      theta=s["theta"], cross_kv=cross)
         return x, None
 
     fn = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable) \
