@@ -131,16 +131,7 @@ def scoped_busy(devices: Sequence[Sequence[ScopedEvent]],
     w0, w1 = _window(spans)
     out: Dict[str, float] = collections.defaultdict(float)
     for events in devices:
-        evs = sorted(events, key=lambda e: (e[1], -e[2]))
-        excl = [max(0, min(s + d, w1) - max(s, w0)) for _, s, d, _ in evs]
-        stack: List[int] = []
-        for i, (_, s, d, _) in enumerate(evs):
-            while stack and evs[stack[-1]][1] + evs[stack[-1]][2] <= s:
-                stack.pop()
-            if stack:
-                excl[stack[-1]] -= max(0, min(s + d, w1) - max(s, w0))
-            stack.append(i)
-        for e, x in zip(evs, excl):
+        for e, x in zip(events, tracereduce.exclusive_ns(events, w0, w1)):
             if x > 0:
                 out[key(e[3])] += x / 1e9
     n = max(len(devices), 1)

@@ -1,2 +1,2 @@
 """Plain float32 references, one module per architecture, named by the
-``reference`` key of a configuration file."""
+``REFERENCE`` of each module under ``bench/arch/``."""

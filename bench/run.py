@@ -3,15 +3,17 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell is comes from data: ``BENCHMARK.json`` names its
-configuration (``bench/configs/<config>.json``: the published sizes of each
-pool member, the planted agreement, the slot count and the comparison's
-limits), its traffic mix (``bench/traffic/<mix>.json``) and its metrics (one
-reader each, ``bench/metrics/<metric>.py``).
+configuration (``bench/configs/<config>.json``: the published sizes and the
+architecture of each pool member, the planted agreement, the slot count and
+the comparison's limits), its traffic mix (``bench/traffic/<mix>.json``) and
+its metrics (one reader each, ``bench/metrics/<metric>.py``).  Each
+member's ``arch`` names its module, ``bench/arch/<arch>.py`` (the interface
+is in ``bench/arch/__init__.py``).
 
 A run builds the pool's bf16 weights on the device from ``--seed``, serves
 the mix through ``ServingEngine`` with the router's defaults, warms every
 shape the mix uses, then measures for ``--seconds``.  After the window it
-replays every request through the configuration's float32 reference
+replays every request through the target architecture's float32 reference
 (``bench/check.py``).  Earlier lines report the set-up split, the row
 capacity, the programs compiled inside the window (there should be none;
 the result's ``window_compiles`` counts them) and the comparison; the
@@ -29,7 +31,6 @@ import argparse  # noqa: E402
 import collections  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -48,11 +49,11 @@ for p in (str(ROOT / "src"), str(ROOT)):
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
+from bench import phases  # noqa: E402
 from bench import tracereduce  # noqa: E402
 from bench import traffic as tg  # noqa: E402
 from bench import weights as wt  # noqa: E402
 from bench.check import judge  # noqa: E402
-from bench.stats import forward_flops_per_token  # noqa: E402
 
 _COMPILE = "/jax/core/compile/backend_compile_duration"
 _TRACE = "/jax/core/compile/jaxpr_trace_duration"
@@ -84,61 +85,60 @@ def load_cell(workload: str, root: Path = ROOT):
             [m for m in manifest["per_layer"] if applies(m)])
 
 
-def reader(name: str, root: Path = ROOT):
-    """The ``read(run)`` function of a metric, from its own file."""
-    path = root / "bench" / "metrics" / f"{name}.py"
+def _module(kind: str, name: str, root: Path):
+    """``bench/<kind>/<name>.py`` under ``root``, loaded from its file."""
+    path = root / "bench" / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-def peak_flops(device_kind: str) -> float:
+def reader(name: str, root: Path = ROOT):
+    """The ``read(run)`` function of a metric, from its own file."""
+    return _module("metrics", name, root).read
+
+
+def arch_module(name: str, root: Path = ROOT):
+    """The module of architecture ``name`` (``bench/arch/<name>.py``)."""
+    return _module("arch", name, root)
+
+
+def device_peaks(device_kind: str) -> Dict:
+    """The device's row of ``bench/peaks.json``."""
     peaks = json.loads((BENCH / "peaks.json").read_text())
     if device_kind not in peaks:
         raise KeyError(f"no published peaks for {device_kind!r}")
-    return float(peaks[device_kind]["bf16_flops"])
+    return peaks[device_kind]
 
 
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
-def program_config(member: Dict):
-    import jax.numpy as jnp
-    from repro.models.config import ModelConfig
-    hf = member["config"]
-    return ModelConfig(
-        name=member["name"], arch_type="dense",
-        num_layers=hf["num_hidden_layers"], d_model=hf["hidden_size"],
-        num_heads=hf["num_attention_heads"],
-        num_kv_heads=hf["num_key_value_heads"],
-        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
-        d_ff=hf["intermediate_size"], vocab_size=hf["vocab_size"],
-        qkv_bias=True, rope_theta=hf["rope_theta"],
-        rms_eps=hf["rms_norm_eps"],
-        tie_embeddings=hf["tie_word_embeddings"],
-        max_position=hf["max_position_embeddings"], dtype=jnp.bfloat16,
-        source=member["source"])
-
-
 class Serving:
     """The pool, its engine, and the benchmark's own copy of the weights
-    (the reference reads those, never the program's)."""
+    (the reference reads those, never the program's).  Each member's
+    weights, program configuration and parameter tree come from its
+    architecture's module, ``archs[name]``."""
 
-    def __init__(self, cfg: Dict, seed: int):
+    def __init__(self, cfg: Dict, seed: int, root: Path = ROOT):
         from repro.core import ModelPool
         from repro.models.model import LanguageModel
         from repro.serving import ServingEngine
         self.cfg = cfg
         self.members = cfg["members"]
         self.target = self.members[-1]["name"]
+        self.archs = {m["name"]: arch_module(m["arch"], root)
+                      for m in self.members}
         self.weights: Dict[str, Dict] = {}
         self.make_weights(seed)
         self.pool = ModelPool()
         for m in self.members:
-            pc = program_config(m)
-            self.pool.register(pc, params=wt.to_program(self.weights[m["name"]]),
+            arch = self.archs[m["name"]]
+            pc = arch.program_config(m)
+            self.pool.register(pc,
+                               params=arch.to_program(self.weights[m["name"]]),
                                param_axes=LanguageModel(pc).param_axes())
         self.slots = int(cfg["slots"])
         self.engine = ServingEngine(self.pool, self.target,
@@ -155,12 +155,13 @@ class Serving:
         gc.collect()
         for i in reversed(range(len(self.members))):
             m = self.members[i]
-            w = wt.make_weights(m["config"], self.cfg["planting"],
-                                m["planted"], wt.member_key(seed, i))
+            arch = self.archs[m["name"]]
+            w = arch.make_weights(m["config"], self.cfg["planting"],
+                                  m["planted"], wt.member_key(seed, i))
             jax.block_until_ready(w)
             self.weights[m["name"]] = w
             if getattr(self, "pool", None) is not None:
-                self.pool.entry(m["name"]).params = wt.to_program(w)
+                self.pool.entry(m["name"]).params = arch.to_program(w)
 
     @property
     def router(self):
@@ -378,7 +379,13 @@ def serve_window(serving: Serving, mix: Dict, seed: int, seconds: float):
 
 @dataclasses.dataclass
 class Run:
-    """What the metric readers see of one run."""
+    """What the metric readers see of one run.  ``spans`` and
+    ``counters`` are what the window added to the program's span table
+    (``{name: [count, s, self s]}``) and counters; ``scoped_busy`` (traced
+    runs) is the window's exclusive device seconds by each op's scope path
+    (``jit(<program>)/<scope>/.../<primitive>``, ``""`` where the trace
+    names none), averaged over the chips; ``peaks`` the device's row of
+    ``bench/peaks.json`` (empty off the TPU)."""
     requests: List
     wall_s: float
     setup_s: float
@@ -388,6 +395,10 @@ class Run:
     trace: Optional[Dict]
     target_flops_per_token: float
     peak_flops: float
+    spans: Dict[str, List[float]] = dataclasses.field(default_factory=dict)
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
+    scoped_busy: Dict[str, float] = dataclasses.field(default_factory=dict)
+    peaks: Dict = dataclasses.field(default_factory=dict)
 
 
 def mean_context(reqs) -> float:
@@ -399,8 +410,10 @@ def mean_context(reqs) -> float:
     return s / n if n else 0.0
 
 
-def reference_module(cfg: Dict):
-    return importlib.import_module(f"bench.reference.{cfg['reference']}")
+def reference_module(cfg: Dict, root: Path = ROOT):
+    """The float32 reference of the pool's target: its architecture's
+    ``REFERENCE``."""
+    return arch_module(cfg["members"][-1]["arch"], root).REFERENCE
 
 
 def use_compile_cache(cache_dir: Optional[Path]) -> None:
@@ -442,7 +455,7 @@ def _run_cell(workload, seed, seconds, trace, require_tpu, root, t_start,
     import repro.serving  # noqa: F401  (the program's import cost is set-up)
     t_import = time.perf_counter()
 
-    serving = Serving(cfg, seed)
+    serving = Serving(cfg, seed, root)
     t_weights = time.perf_counter()
     cap = offered_cap(serving, mix, seed)
     warm_up(serving, mix, seed, cap)
@@ -457,6 +470,9 @@ def _run_cell(workload, seed, seconds, trace, require_tpu, root, t_start,
 
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     recorder = Recorder(serving, trace_dir) if trace else None
+    prof = serving.router.profiler
+    spans0 = {k: list(v) for k, v in prof.spans.items()}
+    counters0 = dict(prof.counters)
     if trace:
         recorder.__enter__()
     try:
@@ -464,6 +480,9 @@ def _run_cell(workload, seed, seconds, trace, require_tpu, root, t_start,
     finally:
         if trace:
             recorder.__exit__(None, None, None)
+    spans = phases.span_delta(spans0, prof.spans)
+    counters = {k: v - counters0.get(k, 0.0) for k, v in
+                prof.counters.items() if v != counters0.get(k, 0.0)}
     if trace:
         wall -= recorder.stop_s
     after, _, _ = counter.snapshot()
@@ -489,7 +508,8 @@ def _run_cell(workload, seed, seconds, trace, require_tpu, root, t_start,
     gc.collect()
     t_ref = time.perf_counter()
     target = serving.members[-1]
-    verdict = judge(reference_module(cfg), serving.weights[target["name"]],
+    target_arch = serving.archs[target["name"]]
+    verdict = judge(target_arch.REFERENCE, serving.weights[target["name"]],
                     target["config"], reqs, cfg["limits"])
     print("check " + json.dumps(dict(
         reference_s=time.perf_counter() - t_ref, tokens=verdict["tokens"],
@@ -497,32 +517,41 @@ def _run_cell(workload, seed, seconds, trace, require_tpu, root, t_start,
         flush=True)
 
     reduced = None
+    scoped_busy: Dict[str, float] = {}
     if trace:
-        devs, spans, layout = tracereduce.read_xplane(trace_dir, SPANS)
-        reduced = tracereduce.reduce(devs[:cell["chips"]], spans)
+        t_read = time.perf_counter()
+        devs, host, layout = tracereduce.read_xplane(trace_dir, SPANS)
+        reduced = tracereduce.reduce(devs[:cell["chips"]], host)
+        scoped, scope_stat = phases.read_scoped(trace_dir)
+        scoped_busy = phases.scoped_busy(scoped[:cell["chips"]], host,
+                                         key=lambda path: path)
         shutil.rmtree(trace_dir, ignore_errors=True)
         ev = [e for d in devs[:cell["chips"]] for e in d]
-        win = [s for s in spans if s[0] == tracereduce.WINDOW_SPAN]
+        win = [s for s in host if s[0] == tracereduce.WINDOW_SPAN]
         print("trace " + json.dumps(dict(
             device_planes=layout, device_events=len(ev),
             device_extent_ns=[min(e[1] for e in ev),
                               max(e[1] + e[2] for e in ev)] if ev else None,
             window_ns=[win[0][1], win[0][1] + win[0][2]] if win else None,
-            host_spans=len(spans))), flush=True)
+            host_spans=len(host), scope_stat=scope_stat,
+            scope_paths=len(scoped_busy),
+            read_s=time.perf_counter() - t_read)), flush=True)
         print("classes " + json.dumps({
             cls: dict(slot_cycles=len(v), tokens_per_slot_cycle=
                       sum(v) / len(v)) for cls, v in
             recorder.class_commits.items()}), flush=True)
 
+    peaks = device_peaks(dev.device_kind) if dev.platform == "tpu" else {}
     run = Run(requests=reqs, wall_s=wall, setup_s=setup_s,
               committed_tokens=committed,
               cycles=recorder.cycles if trace else [],
               admit_s=recorder.admit_s if trace else [],
               trace=reduced,
-              target_flops_per_token=forward_flops_per_token(
+              target_flops_per_token=target_arch.flops_per_token(
                   target["config"], mean_context(reqs)),
-              peak_flops=peak_flops(dev.device_kind)
-              if dev.platform == "tpu" else float("nan"))
+              peak_flops=float(peaks.get("bf16_flops", "nan")),
+              spans=spans, counters=counters, scoped_busy=scoped_busy,
+              peaks=peaks)
     metrics = {}
     for m in (per_layer if trace else e2e):
         v = reader(m["name"], root)(run)

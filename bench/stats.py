@@ -1,15 +1,18 @@
 """Shared arithmetic of the benchmark's metrics."""
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 
-def forward_flops_per_token(hf: Dict, context: float) -> float:
-    """Forward FLOPs of one token through a dense Qwen2-style model: two
-    per weight of every matrix product (output head included, embedding
-    lookup not) plus the attention products over ``context`` keys."""
-    d, L = hf["hidden_size"], hf["num_hidden_layers"]
-    H, Hkv = hf["num_attention_heads"], hf["num_key_value_heads"]
-    hd, ff, V = d // H, hf["intermediate_size"], hf["vocab_size"]
-    per_layer = d * (H + 2 * Hkv) * hd + H * hd * d + 3 * d * ff
-    return 2.0 * (L * per_layer + d * V) + 4.0 * L * H * hd * context
+def roofline_share(flops: float, bytes_: float, seconds: float,
+                   peaks: Dict) -> Optional[float]:
+    """A kernel's share of the chip's roofline, in %: the least time the
+    chip could take for ``flops`` operations and ``bytes_`` of HBM traffic
+    (the larger of the two over the device's ``bf16_flops`` and
+    ``hbm_bytes_per_s`` peaks, ``Run.peaks``) over the ``seconds`` it took.
+    None where there is no time or no work to share."""
+    if seconds <= 0 or not peaks:
+        return None
+    least = max(flops / peaks["bf16_flops"],
+                bytes_ / peaks["hbm_bytes_per_s"])
+    return 100.0 * least / seconds if least > 0 else None

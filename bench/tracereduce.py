@@ -8,7 +8,8 @@ The benchmark's traced run wraps its window in a host span named
   line of each ``/device:`` plane) inside the window, averaged over the
   chips in use;
 * ``device_ops``: the operations that took most device time, summed by
-  name;
+  name, each op counting its exclusive time (``exclusive_ns``: a loop
+  does not count the ops that run inside it);
 * ``idle_gaps``: the longest stretches with no device operation, each
   named by the innermost benchmark span that covers its middle (``engine``
   where only the window covers it).
@@ -74,6 +75,28 @@ def _merge(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
     return [(s, e) for s, e in out]
 
 
+def exclusive_ns(events: Sequence[Tuple], w0: int, w1: int) -> List[int]:
+    """Nanoseconds of each event (``(name, start_ns, duration_ns, ...)``
+    of one device line) inside [w0, w1) that no event nested in it covers,
+    in the order given: a loop and the fusions it runs are counted once.
+    An event that starts inside another and ends after it gives up only
+    the part they share."""
+    def inside(a: int, b: int) -> int:
+        return max(0, min(b, w1) - max(a, w0))
+    excl = [inside(e[1], e[1] + e[2]) for e in events]
+    stack: List[int] = []
+    for i in sorted(range(len(events)),
+                    key=lambda i: (events[i][1], -events[i][2])):
+        s, d = events[i][1], events[i][2]
+        while stack and events[stack[-1]][1] + events[stack[-1]][2] <= s:
+            stack.pop()
+        if stack:
+            p = events[stack[-1]]
+            excl[stack[-1]] -= inside(s, min(s + d, p[1] + p[2]))
+        stack.append(i)
+    return excl
+
+
 def reduce(devices: Sequence[Sequence[Event]], spans: Sequence[Event]
            ) -> Dict:
     """busy_s, window_s, device_ops and idle_gaps of one traced window."""
@@ -89,12 +112,13 @@ def reduce(devices: Sequence[Sequence[Event]], spans: Sequence[Event]
     gaps: List[Tuple[int, int]] = []
     for events in devices:
         iv = []
-        for name, s, d in events:
+        for (name, s, d), own in zip(events, exclusive_ns(events, w0, w1)):
             a, b = max(s, w0), min(s + d, w1)
             if b <= a:
                 continue
             iv.append((a, b))
-            op_time[name] = op_time.get(name, 0) + (b - a)
+            if own > 0:
+                op_time[name] = op_time.get(name, 0) + own
         merged = _merge(iv)
         busy_total += sum(b - a for a, b in merged)
         edges = [w0] + [x for ab in merged for x in ab] + [w1]

@@ -1,10 +1,12 @@
 """Seeded weights with planted draft agreement, made on the device.
 
 Random weights accept nothing, so agreement is planted, and it is planted
-in the embedding and the output head only: every layer keeps dense random
-weights at full width and full cost.  The vocabulary holds one disjoint
-token range per difficulty class (the configuration's ``planting``).  For
-each member, with ``x`` the last hidden state and ``rms`` its RMS:
+in the embedding and the output head only: every layer keeps random
+weights at full width and full cost, laid out by the member's architecture
+module (``bench/arch/<arch>.py``), which calls ``make``.  The vocabulary
+holds one disjoint token range per difficulty class (the configuration's
+``planting``).  For each member, with ``x`` the last hidden state and
+``rms`` its RMS:
 
 * every class token ``v`` of class ``c`` embeds as
   ``kappa_c + beta + s_c * a_v``: a class direction, an anchor shared by
@@ -39,46 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# per-layer leaves of the benchmark's own layout, stacked over layers
-LAYER_LEAVES = ("ln1", "wq", "bq", "wk", "bk", "wv", "bv", "wo",
-                "ln2", "wg", "wu", "wd")
-
-
-def dims(hf: Dict) -> Dict[str, int]:
-    """Shape numbers of one member, from its published config keys."""
-    d, h = hf["hidden_size"], hf["num_attention_heads"]
-    return dict(d=d, L=hf["num_hidden_layers"], H=h,
-                Hkv=hf["num_key_value_heads"], hd=d // h,
-                ff=hf["intermediate_size"], V=hf["vocab_size"],
-                tied=bool(hf["tie_word_embeddings"]))
-
 
 def member_key(seed: int, index: int) -> jax.Array:
     """Per-member PRNG key from a seed of any size."""
     s = np.random.default_rng([int(seed) % 2**63, 7, index])
     return jax.random.PRNGKey(int(s.integers(0, 2**31 - 1)))
-
-
-def _layers(key, n: Dict[str, int], p: Dict, dt):
-    L, d, H, Hkv, hd, ff = (n[k] for k in ("L", "d", "H", "Hkv", "hd", "ff"))
-    shapes = {
-        "ln1": (L, d), "ln2": (L, d),
-        "wq": (L, d, H * hd), "wk": (L, d, Hkv * hd), "wv": (L, d, Hkv * hd),
-        "bq": (L, H * hd), "bk": (L, Hkv * hd), "bv": (L, Hkv * hd),
-        "wo": (L, H * hd, d), "wg": (L, d, ff), "wu": (L, d, ff),
-        "wd": (L, ff, d),
-    }
-    keys = dict(zip(LAYER_LEAVES, jax.random.split(key, len(LAYER_LEAVES))))
-    out = {}
-    for name, shape in shapes.items():
-        z = jax.random.normal(keys[name], shape, dt)
-        if name.startswith("ln"):
-            out[name] = (1.0 + p["norm_jitter"] * z).astype(dt)
-        elif name.startswith("b"):
-            out[name] = (p["bias_scale"] * z).astype(dt)
-        else:                       # fan-in scaled, as a fresh init
-            out[name] = (z / math.sqrt(shape[1])).astype(dt)
-    return out
 
 
 def _embed_and_head(key, n: Dict[str, int], planting: Dict, member: Dict,
@@ -115,14 +82,14 @@ def _embed_and_head(key, n: Dict[str, int], planting: Dict, member: Dict,
     return emb.astype(dt), head.astype(dt)
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3))
-def _make(key, n_items, planting_items, member_items):
+@partial(jax.jit, static_argnums=(0, 2, 3, 4))
+def _make(layers, key, n_items, planting_items, member_items):
     n = dict(n_items)
     planting = _thaw(planting_items)
     member = _thaw(member_items)
     dt = jnp.bfloat16
     k_layers, k_io = jax.random.split(key)
-    w = _layers(k_layers, n, planting, dt)
+    w = layers(k_layers, n, planting, dt)
     w["embed"], head = _embed_and_head(k_io, n, planting, member, dt)
     if head is not None:
         w["head"] = head
@@ -147,29 +114,11 @@ def _thaw(x):
     return x
 
 
-def make_weights(hf: Dict, planting: Dict, member: Dict, key) -> Dict:
-    """One member's bf16 weights in the benchmark's own layout, made in one
-    jitted call on the default device."""
-    return _make(key, tuple(sorted(dims(hf).items())), _freeze(planting),
+def make(layers, n: Dict, planting: Dict, member: Dict, key) -> Dict:
+    """One member's bf16 weights, made in one jitted call on the default
+    device: ``layers(key, n, planting, dtype)`` gives the architecture's
+    own leaves, and the embedding, the head (untied) and the final norm
+    are planted here.  ``n`` holds the architecture's shape numbers, among
+    them ``d`` (hidden width), ``V`` (vocabulary) and ``tied``."""
+    return _make(layers, key, tuple(sorted(n.items())), _freeze(planting),
                  _freeze(member))
-
-
-def to_program(w: Dict) -> Dict:
-    """The same arrays, nested as the serving program's parameter tree
-    (``repro.models.transformer``); nothing is copied."""
-    tree = {
-        "embed": w["embed"],
-        "blocks": {
-            "ln1": {"scale": w["ln1"]}, "ln2": {"scale": w["ln2"]},
-            "attn": {"q": {"w": w["wq"], "b": w["bq"]},
-                     "k": {"w": w["wk"], "b": w["bk"]},
-                     "v": {"w": w["wv"], "b": w["bv"]},
-                     "o": {"w": w["wo"]}},
-            "mlp": {"gate": {"w": w["wg"]}, "up": {"w": w["wu"]},
-                    "down": {"w": w["wd"]}},
-        },
-        "final_norm": {"scale": w["final_norm"]},
-    }
-    if "head" in w:
-        tree["lm_head"] = {"w": w["head"]}
-    return tree

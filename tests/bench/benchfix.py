@@ -20,7 +20,7 @@ _COMMON = {"hidden_act": "silu", "max_position_embeddings": 4096,
 
 
 def _member(name, d, ff, heads, layers, tied, code_scale, copies):
-    return {"name": name, "source": "test",
+    return {"name": name, "source": "test", "arch": "qwen2_dense",
             "config": dict(_COMMON, hidden_size=d, intermediate_size=ff,
                            num_attention_heads=heads,
                            num_hidden_layers=layers,
@@ -37,8 +37,7 @@ def tiny_config() -> dict:
     planting = dict(planting, classes={"easy": [1000, 256],
                                        "medium": [2000, 256],
                                        "hard": [3000, 256]})
-    return {"name": "tiny", "source": "test", "reference": "qwen2_dense",
-            "dtype": "bfloat16",
+    return {"name": "tiny", "source": "test", "dtype": "bfloat16",
             "members": [
                 _member("tiny-draft", 64, 128, 4, 2, True,
                         {"easy": 2.0, "medium": 0.05, "hard": 1.0}, []),

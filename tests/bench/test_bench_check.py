@@ -61,11 +61,11 @@ def test_control_reads_above_the_limit_and_the_program_below(tiny_tree):
     every seed ``judge`` passes the served tokens, and judges the tokens
     that fp8 puts first at the same positions not correct."""
     from bench.check import judge
-    from bench.reference import qwen2_dense as ref
     _, cfg, mix, _, _ = br.load_cell("tiny.closed", tiny_tree)
+    ref = br.reference_module(cfg, tiny_tree)
     limit = cfg["limits"]["max_gap"]
     target = cfg["members"][-1]
-    serving = br.Serving(cfg, 21)
+    serving = br.Serving(cfg, 21, tiny_tree)
     for seed in (21, 22, 23):
         serving.make_weights(seed)
         reqs, _ = br.serve_window(serving, mix, seed, 1.0)

@@ -21,10 +21,24 @@ def test_reduce_gives_busy_idle_and_breakdown():
     out = tr.reduce(dev, spans)
     assert out["window_s"] == pytest.approx(0.050)
     assert out["busy_s"] == pytest.approx(0.030)     # union, clipped
+    # exclusive time: the first a gives up the 5 ms that b shares with it
     assert dict((k, v) for k, v in out["device_ops"]) == pytest.approx(
-        {"a": 0.020, "b": 0.015})
+        {"a": 0.015, "b": 0.015})
     assert out["idle_gaps"] == [["run_cycle", pytest.approx(0.010)],
                                 ["admit", pytest.approx(0.010)]]
+
+
+def test_reduce_counts_a_loop_and_its_ops_once():
+    """``device_ops`` gives each op its exclusive time, as
+    ``phases.scoped_busy`` does: a loop less the fusions it runs."""
+    dev = [[("while.13", 0, 10 * MS), ("fusion.1", 1 * MS, 3 * MS),
+            ("fusion.2", 5 * MS, 2 * MS), ("copy.1", 12 * MS, 4 * MS)]]
+    out = tr.reduce(dev, [("window", 0, 15 * MS)])
+    ops = dict((k, v) for k, v in out["device_ops"])
+    assert ops == pytest.approx({"while.13": 0.005, "fusion.1": 0.003,
+                                 "fusion.2": 0.002, "copy.1": 0.003})
+    assert sum(ops.values()) == pytest.approx(out["busy_s"])
+    assert [k for k, _ in out["device_ops"]][0] == "while.13"
 
 
 def test_reduce_averages_busy_over_chips():

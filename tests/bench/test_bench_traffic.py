@@ -31,7 +31,7 @@ def _row_cap(cell, need):
     _, cfg, _, _, _ = br.load_cell(cell)
     pool = ModelPool()
     for m in cfg["members"]:
-        pool.register(br.program_config(m))
+        pool.register(br.arch_module(m["arch"]).program_config(m))
     router = ChainRouter(pool, cfg["members"][-1]["name"], **cfg["router"])
     return br.Serving.row_cap(SimpleNamespace(router=router), need)
 
