@@ -207,6 +207,9 @@ class ChainRouter:
         self.gcap = max(max(windows), depth_max) + max_chain_len + 2
         self.max_block = max(max(windows),
                              max((t.num_nodes for t in trees), default=0))
+        # the widest block any one forward appends (the widest gap bucket
+        # plus a window): what a recurrent member keeps for rollback
+        self.rollback_span = self.gcap + 1 + self.max_block
 
     # ------------------------------------------------------------------
     def _next_rng(self):
@@ -231,7 +234,7 @@ class ChainRouter:
         probs, _sid = self.executor.prefill(PrefillRequest(
             model=m, request_id=request_id, tokens=seq.astype(np.int32),
             valid=valid, max_len=max_len,
-            with_snaps=cfg.arch_type in ("ssm", "hybrid"),
+            rollback_span=self.rollback_span if cfg.recurrent else 0,
             paged=self.paged, extras=extras))
         return probs
 
@@ -1224,6 +1227,25 @@ class RouterSession:
         with r.profiler.span("cycle.mirror"):
             return self._mirror_summary(choice, gmask, slot_keys, bufs, s)
 
+    def _count_restores(self, choice: ChainChoice, run: np.ndarray,
+                        accepts: np.ndarray) -> None:
+        """Count the rows whose recurrent state a linear cycle's rollback
+        restored (``restore_rows``), from the accepted counts the summary
+        carries: chain member ``i`` rolls back ``W + i - min(k_i, ...,
+        k_N)``, the target ``W + N - 2 - k_N`` (``consensus_rollbacks``)."""
+        chain = choice.chain
+        if len(chain) < 2 or choice.tree is not None:
+            return
+        r = self.router
+        for i, m in enumerate(chain):
+            if not r.pool.cfg(m).recurrent:
+                continue
+            lvl = min(i, len(chain) - 2)
+            tc = choice.window + lvl
+            kept = np.min(accepts[lvl:], axis=0)
+            r.profiler.count("restore_rows",
+                             float(np.sum(run & (kept < tc))))
+
     def _mirror_summary(self, choice: ChainChoice, gmask: np.ndarray,
                         slot_keys: Optional[Sequence[str]], bufs,
                         s) -> np.ndarray:
@@ -1234,6 +1256,7 @@ class RouterSession:
         tree = choice.tree if (choice.tree is not None
                                and len(chain) > 1) else None
         self._dev.update(bufs)
+        self._count_restores(choice, gmask & self.active, s.accepts)
         # --- mirror the one-transfer summary onto the host ----------------
         cnum = s.n_committed.astype(np.int64)
         rows = np.where(cnum > 0)[0]

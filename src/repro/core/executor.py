@@ -55,7 +55,7 @@ class PrefillRequest:
     tokens: np.ndarray            # (B, Tp) int32
     valid: np.ndarray             # (B, Tp) bool
     max_len: int
-    with_snaps: bool = False
+    rollback_span: int = 0        # widest block a rollback may undo
     paged: bool = True            # paged KV state (archs that support it)
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
@@ -445,7 +445,7 @@ class Executor:
         sid = StateManager.key(req.model, req.request_id)
         B = req.tokens.shape[0]
         state, state_axes = lm.make_state(B, req.max_len,
-                                          with_snaps=req.with_snaps,
+                                          rollback_span=req.rollback_span,
                                           paged=req.paged)
         # allocate the fresh KV state under the member's placement (the
         # same sharding.py rules that placed the params shard the KV block
@@ -613,6 +613,8 @@ class Executor:
         SSM archs restore snapshots first — model.rollback handles both)."""
         sid = StateManager.key(req.model, req.request_id)
         state = self.states.get(sid)
+        if self.pool.cfg(req.model).recurrent:
+            self.profiler.count("restore_rows", float(np.sum(req.r > 0)))
         with self.profiler.timed("rollback", self._pq(req.model),
                                  tokens=int(req.r.sum())), self._mctx():
             state = self._rollback(req.model)(state, jnp.asarray(req.r))

@@ -20,6 +20,15 @@ class MoEConfig:
     aux_loss_coef: float = 0.01
     num_shared_experts: int = 0    # kimi-k2 style shared expert(s)
     d_shared: int = 0
+    # expert parallelism seen from one chip: the router scores all
+    # ``num_experts`` and this chip computes the part of the result that
+    # experts [held_start, held_start + held) give (0 = all of them)
+    held_start: int = 0
+    held: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,6 +42,19 @@ class SSMConfig:
     slstm_every: int = 0           # every k-th block is sLSTM (0 = none)
     mlstm_proj_factor: float = 2.0
     slstm_proj_factor: float = 1.334
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearAttnConfig:
+    """Gated DeltaNet token mixer (Qwen3-Next): every
+    ``full_attention_interval``-th layer is full attention, the others
+    are linear attention with a per-row delta state."""
+    num_key_heads: int
+    num_value_heads: int
+    key_head_dim: int
+    value_head_dim: int
+    conv_kernel: int = 4
+    full_attention_interval: int = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +76,7 @@ class VLMConfig:
 class ModelConfig:
     name: str
     arch_type: str                 # dense | moe | ssm | hybrid | audio | vlm
+    #                                | qwen3_next
     num_layers: int
     d_model: int
     num_heads: int
@@ -77,7 +100,9 @@ class ModelConfig:
     qk_norm: bool = False          # gemma3: rmsnorm on q,k heads
     kv_quant: bool = False         # int8 KV cache (beyond-paper, §Perf G2)
     rope_theta_global: float = 0.0  # gemma3: different theta on global layers
+    partial_rotary: float = 1.0    # share of head_dim that RoPE rotates
     moe: Optional[MoEConfig] = None
+    linear_attn: Optional[LinearAttnConfig] = None
     ssm: Optional[SSMConfig] = None
     encdec: Optional[EncDecConfig] = None
     vlm: Optional[VLMConfig] = None
@@ -103,9 +128,17 @@ class ModelConfig:
         # k local layers then 1 global, repeating (gemma3 = 5:1)
         return (layer_idx + 1) % (self.local_global_ratio + 1) == 0
 
+    @property
+    def recurrent(self) -> bool:
+        """Carries per-row recurrent state beside (or instead of) a
+        per-position cache: rollback restores it, and a token tree cannot
+        branch it."""
+        return self.arch_type in ("ssm", "hybrid") \
+            or self.linear_attn is not None
+
     def supports_long_context(self) -> bool:
-        """Sub-quadratic-capable: SSM, hybrid, or sliding-window dense."""
-        return self.arch_type in ("ssm", "hybrid") or self.sliding_window > 0
+        """Sub-quadratic-capable: recurrent, or sliding-window dense."""
+        return self.recurrent or self.sliding_window > 0
 
     def has_decode_step(self) -> bool:
         return True  # all assigned archs are decoder-bearing
@@ -113,14 +146,16 @@ class ModelConfig:
     @property
     def supports_tree(self) -> bool:
         """Tree-structured speculation needs per-position KV that can mask
-        dead branches; recurrent carries (SSM/hybrid) cannot branch."""
-        return self.arch_type in ("dense", "moe", "audio", "vlm")
+        dead branches; recurrent carries cannot branch."""
+        return not self.recurrent
 
     @property
     def supports_paged(self) -> bool:
-        """Paged KV needs a purely per-position cache; recurrent carries
-        (SSM/hybrid) keep the contiguous state + snapshot rings."""
-        return self.arch_type in ("dense", "moe", "audio", "vlm")
+        """Paged KV needs a per-position cache.  The linear-attention
+        mixer keeps its recurrent state as per-row leaves of the paged
+        state; SSM/hybrid carries keep the contiguous state + snapshot
+        rings."""
+        return not self.recurrent or self.linear_attn is not None
 
     def param_count(self) -> int:
         """Analytic parameter count (used for MODEL_FLOPS roofline term)."""

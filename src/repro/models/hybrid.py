@@ -120,8 +120,9 @@ def layer_flags(cfg: ModelConfig):
     return jnp.array([i in glb for i in range(L)], jnp.bool_)
 
 
-def make_cache(cfg: ModelConfig, batch: int, max_len: int,
-               with_snaps: bool = False):
+def make_cache(cfg: ModelConfig, batch: int, max_len: int, span: int = 0):
+    """``span > 0`` keeps the snapshot ring (``SNAP_SLOTS`` states, what
+    rollback restores from)."""
     inner = _inner(cfg)
     N = cfg.ssm.state_size
     L = cfg.num_layers
@@ -129,7 +130,7 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int,
                                  cfg.head_dim, cfg.dtype)
     layers["ssm_h"] = jnp.zeros((L, batch, inner, N), jnp.float32)
     layers["conv"] = jnp.zeros((L, batch, 3, inner), cfg.dtype)
-    if with_snaps:
+    if span > 0:
         layers["snaps"] = {
             "ssm_h": jnp.zeros((SNAP_SLOTS, L, batch, inner, N), jnp.float32),
             "conv": jnp.zeros((SNAP_SLOTS, L, batch, 3, inner), cfg.dtype),
@@ -137,7 +138,7 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int,
     axes = kvc.attn_cache_axes()
     axes["ssm_h"] = ("layers", "batch", "ssm_inner", "ssm_state")
     axes["conv"] = ("layers", "batch", None, "ssm_inner")
-    if with_snaps:
+    if span > 0:
         axes["snaps"] = jax.tree.map(lambda _: None, layers["snaps"])
     return layers, axes
 
@@ -331,3 +332,9 @@ def forward_train(params, cfg: ModelConfig, tokens, remat=True, **_ignored):
     x, _ = _forward(params, cfg, None, tokens, valid, causal, m_win,
                     pos, None, with_cache=False)
     return tf._unembed(params, cfg, x)
+
+
+def restore(state: kvc.ModelState, r: jnp.ndarray) -> kvc.ModelState:
+    """Snapshot restore where the state keeps snapshots (a state made
+    without them is never rolled back past its attention KV)."""
+    return rollback_hybrid(state, r) if "snaps" in state.layers else state

@@ -37,8 +37,10 @@ defragment, no masked-hole leak across slots), and rollback/``resolve_tree``
 stay pure block-table + mask edits — the same zero-copy guarantees as the
 pointer rewind.  Per-layer attention caches are pool-shaped
 ``(L, P·bs, Hkv, hd)``; rows address them through the block table
-(``physical_slots`` / ``physical_view_index``).  Recurrent carries
-(SSM/hybrid) keep the contiguous state + snapshot rings.
+(``physical_slots`` / ``physical_view_index``).  Per-row recurrent
+leaves (the linear-attention mixer's conv windows and delta states) ride
+in the same paged state beside the pool; SSM/hybrid carries keep the
+contiguous state + snapshot rings.
 """
 from __future__ import annotations
 
@@ -716,9 +718,9 @@ def paged_free_rows(state: PagedModelState, rows: jnp.ndarray,
                     layer_axes=None) -> PagedModelState:
     """O(1) retirement: zero the row's logical buffers, rewind its cursor,
     and push ALL its blocks back on the free stack.  No cache-tensor data
-    movement — the next occupant simply allocates fresh blocks.  (The
-    recurrent-carry wipe of the contiguous path is moot here: paged states
-    are attention-only; SSM/hybrid archs keep the contiguous layout.)"""
+    movement — the next occupant simply allocates fresh blocks.  Per-row
+    leaves (``layer_axes`` names a ``"batch"`` axis: recurrent states,
+    whisper cross-KV) are zeroed for the freed rows."""
     rows = jnp.asarray(rows, bool)
     keep = ~rows
     j = jnp.arange(state.blocks_per_row, dtype=jnp.int32)[None, :]
@@ -734,8 +736,8 @@ def paged_free_rows(state: PagedModelState, rows: jnp.ndarray,
     if layer_axes is None:
         return state
     # pool-shaped attention caches have no batch axis; per-row leaves that
-    # do (e.g. whisper cross-KV) get the same exact wipe as the contiguous
-    # path so a freed row never leaks into its next occupant
+    # do (recurrent states, whisper cross-KV) get the same exact wipe as
+    # the contiguous path so a freed row never leaks into its next occupant
     leaves, treedef = jax.tree.flatten(state.layers)
     ax_leaves = treedef.flatten_up_to(layer_axes)
 

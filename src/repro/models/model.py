@@ -4,7 +4,7 @@ and the dry-run launcher.
 
     lm = LanguageModel(cfg)
     params, axes = lm.init(key)
-    state, state_axes = lm.make_state(batch, max_len, with_snaps=...)
+    state, state_axes = lm.make_state(batch, max_len, rollback_span=...)
     logits, state = lm.prefill(params, state, tokens, **extras)
     logits, state = lm.decode(params, state, tokens, valid=..., **extras)
     state = lm.rollback(state, r)
@@ -18,12 +18,13 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from . import frontends, hybrid, kv_cache as kvc, moe, ssm, transformer as tf
+from . import (frontends, hybrid, kv_cache as kvc, moe, qwen3_next, ssm,
+               transformer as tf)
 from .config import ModelConfig
 
 _FAMILY = {
     "dense": tf, "audio": tf, "vlm": tf,
-    "moe": moe, "ssm": ssm, "hybrid": hybrid,
+    "moe": moe, "ssm": ssm, "hybrid": hybrid, "qwen3_next": qwen3_next,
 }
 
 
@@ -53,27 +54,28 @@ class LanguageModel:
         return state, axes
 
     # ---- state -------------------------------------------------------
-    def make_state(self, batch: int, max_len: int, with_snaps: bool = False,
+    def make_state(self, batch: int, max_len: int, rollback_span: int = 0,
                    paged: bool = False, block_size: int = 0,
                    pool_blocks: int = 0):
         """``paged=True`` builds a PagedModelState (per-row block tables
-        over a shared block pool) for archs with a purely per-position
-        cache; SSM/hybrid silently keep the contiguous layout (their
-        recurrent carries need the snapshot-ring machinery)."""
+        over a shared block pool) where the arch supports it; SSM/hybrid
+        silently keep the contiguous layout (their recurrent carries need
+        the snapshot-ring machinery).  ``rollback_span > 0`` makes a
+        recurrent arch keep what rollback restores: the snapshot rings of
+        SSM/hybrid, or the inputs of up to ``rollback_span`` positions
+        (the widest block the caller runs) of the linear-attention mixer;
+        0 keeps nothing, so no rollback can restore the recurrent state."""
         cfg = self.cfg
+        rec = dict(span=rollback_span) if cfg.recurrent else {}
         if paged and cfg.supports_paged:
             bs = block_size or kvc.PAGE_BLOCK
             layers, axes = self.mod.make_paged_cache(
-                cfg, batch, max_len, bs, pool_blocks or None)
+                cfg, batch, max_len, bs, pool_blocks or None, **rec)
             state = kvc.make_paged_state(batch, max_len, layers,
                                          block_size=bs,
                                          pool_blocks=pool_blocks or None)
             return state, kvc.paged_state_axes(axes, bs)
-        if self.mod in (ssm, hybrid):
-            layers, axes = self.mod.make_cache(cfg, batch, max_len,
-                                               with_snaps=with_snaps)
-        else:
-            layers, axes = self.mod.make_cache(cfg, batch, max_len)
+        layers, axes = self.mod.make_cache(cfg, batch, max_len, **rec)
         state = kvc.make_state(batch, max_len, layers)
         state_axes = kvc.ModelState(
             token_buf=("batch", "seq"), pos_buf=("batch", "seq"),
@@ -125,10 +127,8 @@ class LanguageModel:
 
     # ---- rollback (paper §4.4; SSM snapshot adaptation DESIGN §5) ------
     def rollback(self, state: kvc.ModelState, r: jnp.ndarray):
-        if self.cfg.arch_type == "ssm":
-            state = ssm.rollback_ssm(state, r)
-        elif self.cfg.arch_type == "hybrid" and "snaps" in state.layers:
-            state = hybrid.rollback_hybrid(state, r)
+        if self.cfg.recurrent:
+            state = self.mod.restore(state, r)
         return kvc.rollback(state, r)
 
     # ---- training ------------------------------------------------------

@@ -130,6 +130,46 @@ def moe_ffn(p, cfg: ModelConfig, x: jnp.ndarray):
 
 
 # ---------------------------------------------------------------------------
+# Held-expert FFN: one chip's share of an expert-parallel layer, dropless
+# ---------------------------------------------------------------------------
+def held_expert_ffn(p, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+    """x (B, T, D) -> (B, T, D): the part of the layer's output that the
+    held experts ``[held_start, held_start + held)`` give, plus the shared
+    expert scaled by ``sigmoid(x w_sg)`` (Qwen3-Next's form).
+
+    The router scores all ``num_experts`` (softmax in float32), takes the
+    top-k and renormalises over them; a routed pair whose expert is not
+    held adds nothing.  Every held expert runs on every token with its
+    gate, zero where it was not routed, so no token is ever dropped
+    whatever the batch (no capacity).  Reads every held expert's weights
+    once per call; a gather of only the routed ones would read less.
+
+    p: ``router`` (D, E); ``w_gate``/``w_up`` (H, D, F); ``w_down``
+    (H, F, D); ``shared`` (a SwiGLU) and ``shared_gate`` (D, 1)."""
+    m = cfg.moe
+    logits = jnp.einsum("btd,de->bte", x, p["router"],
+                        preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_v, top_i = jax.lax.top_k(probs, m.top_k)
+    top_v = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
+    held = m.held_start + jnp.arange(m.num_held, dtype=top_i.dtype)
+    gate = jnp.sum(jnp.where(top_i[..., None] == held, top_v[..., None],
+                             0.0), axis=-2)                  # (B, T, H)
+    h = jnp.einsum("btd,edf->btef", x, p["w_gate"],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("btd,edf->btef", x, p["w_up"],
+                   preferred_element_type=jnp.float32)
+    act = (jax.nn.silu(h) * u * gate[..., None]).astype(x.dtype)
+    y = jnp.einsum("btef,efd->btd", act, p["w_down"],
+                   preferred_element_type=jnp.float32)
+    if m.num_shared_experts > 0:
+        y = y + nn.swiglu(p["shared"], x).astype(jnp.float32) \
+            * jax.nn.sigmoid(jnp.einsum("btd,do->bto", x, p["shared_gate"],
+                                        preferred_element_type=jnp.float32))
+    return y.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
 # §Perf K4 (EXPERIMENTS.md pair 2): explicit expert-parallel dispatch via
 # shard_map.  XLA's auto-SPMD lowers the take()-based dispatch into dense
 # all-reduces / full-activation all-gathers of fp32 intermediates; the

@@ -264,8 +264,9 @@ def init(key, cfg: ModelConfig):
     return params, param_axes(cfg)
 
 
-def make_cache(cfg: ModelConfig, batch: int, max_len: int,
-               with_snaps: bool = False):
+def make_cache(cfg: ModelConfig, batch: int, max_len: int, span: int = 0):
+    """``span > 0`` keeps the snapshot ring (``SNAP_SLOTS`` states, what
+    rollback restores from)."""
     G, M = _group_shape(cfg)
     zeros_like_stack = lambda st, *lead: jax.tree.map(
         lambda x: jnp.zeros(lead + x.shape, x.dtype), st)
@@ -276,7 +277,7 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int,
         s0 = slstm_state0(cfg, batch)
         layers["slstm"] = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (G,) + x.shape).copy(), s0)
-    if with_snaps:
+    if span > 0:
         layers["snaps"] = jax.tree.map(
             lambda x: jnp.zeros((SNAP_SLOTS,) + x.shape, x.dtype),
             {k: v for k, v in layers.items() if k != "snaps"})
@@ -559,7 +560,10 @@ def forward_train(params, cfg: ModelConfig, tokens, remat=True,
                             policy=jax.checkpoint_policies.nothing_saveable)
         x, _ = jax.lax.scan(fn, x, gxs)
         return tf._unembed(params, cfg, x)
-    layers, _ = make_cache(cfg, B, 0, with_snaps=False)
+    layers, _ = make_cache(cfg, B, 0)
     valid = jnp.ones((B, S), jnp.bool_)
     _, y = _run_tokens(params, cfg, layers, x, valid)
     return tf._unembed(params, cfg, y)
+
+
+restore = rollback_ssm

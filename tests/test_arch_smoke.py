@@ -23,8 +23,7 @@ def test_forward_and_decode(arch, key):
     lm = LanguageModel(cfg)
     params, axes = lm.init(key)
     B, Tp = 2, 6
-    state, _ = lm.make_state(B, 48,
-                             with_snaps=cfg.arch_type in ("ssm", "hybrid"))
+    state, _ = lm.make_state(B, 48, rollback_span=8)
     toks = jax.random.randint(key, (B, Tp), 0, cfg.vocab_size)
     extras = lm.extras_for(B, key)
     logits, state = lm.prefill(params, state, toks, logits_mode="last",

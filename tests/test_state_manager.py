@@ -121,13 +121,13 @@ def test_ssm_rollback_equals_recompute(arch_kw):
     extra = jnp.array([[11, 12, 13, 14], [15, 16, 17, 18]], jnp.int32)
     nxt = jnp.array([[21, 22], [23, 24]], jnp.int32)
 
-    st1, _ = lm.make_state(B, 32, with_snaps=True)
+    st1, _ = lm.make_state(B, 32, rollback_span=8)
     _, st1 = lm.prefill(params, st1, base)
     _, st1 = lm.decode(params, st1, extra)
     st1 = lm.rollback(st1, jnp.array([2, 2]))
     lg1, _ = lm.decode(params, st1, nxt)
 
-    st2, _ = lm.make_state(B, 32, with_snaps=True)
+    st2, _ = lm.make_state(B, 32, rollback_span=8)
     _, st2 = lm.prefill(params, st2, base)
     _, st2 = lm.decode(params, st2, extra[:, :2])
     lg2, _ = lm.decode(params, st2, nxt)
@@ -146,21 +146,21 @@ def test_ssm_rollback_per_row_divergent():
     extra = jnp.array([[11, 12, 13], [15, 16, 17]], jnp.int32)
     nxt = jnp.array([[21], [23]], jnp.int32)
 
-    st1, _ = lm.make_state(B, 32, with_snaps=True)
+    st1, _ = lm.make_state(B, 32, rollback_span=8)
     _, st1 = lm.prefill(params, st1, base)
     _, st1 = lm.decode(params, st1, extra)
     st1 = lm.rollback(st1, jnp.array([1, 3]))     # divergent rollback
     lg1, _ = lm.decode(params, st1, nxt)
 
     # row 0 reference: kept 2 of the extras
-    st2, _ = lm.make_state(B, 32, with_snaps=True)
+    st2, _ = lm.make_state(B, 32, rollback_span=8)
     _, st2 = lm.prefill(params, st2, base)
     _, st2 = lm.decode(params, st2, extra[:, :2])
     lg2, _ = lm.decode(params, st2, nxt)
     np.testing.assert_allclose(np.asarray(lg1[0]), np.asarray(lg2[0]),
                                rtol=1e-4, atol=1e-4)
     # row 1 reference: kept none
-    st3, _ = lm.make_state(B, 32, with_snaps=True)
+    st3, _ = lm.make_state(B, 32, rollback_span=8)
     _, st3 = lm.prefill(params, st3, base)
     lg3, _ = lm.decode(params, st3, nxt)
     np.testing.assert_allclose(np.asarray(lg1[1]), np.asarray(lg3[1]),
