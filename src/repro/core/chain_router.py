@@ -221,7 +221,9 @@ class ChainRouter:
                        rows: Optional[np.ndarray] = None):
         """(Re-)create model m's state holding seq[:seq_len-1] per row.
         ``rows`` (B,) restricts materialization to those slots (lazy chain
-        membership) — other rows stay empty, zero-length, zero-block."""
+        membership) — other rows stay empty, zero-length, zero-block, and
+        the forward runs over ``rows`` alone.  Returns the fed rows' (k, V)
+        next-token distributions in row order (k = B for ``rows=None``)."""
         eff_len = (seq_len if rows is None
                    else np.where(np.asarray(rows, bool), seq_len, 0))
         S = max(int(eff_len.max()), 1)
@@ -235,7 +237,7 @@ class ChainRouter:
             model=m, request_id=request_id, tokens=seq.astype(np.int32),
             valid=valid, max_len=max_len,
             rollback_span=self.rollback_span if cfg.recurrent else 0,
-            paged=self.paged, extras=extras))
+            paged=self.paged, extras=extras, rows=rows))
         return probs
 
     def _gap_prefix(self, m: str, request_id: str, seq, seq_len, active):
@@ -332,18 +334,18 @@ class ChainRouter:
                      state_rows: Optional[np.ndarray] = None
                      ) -> Optional[np.ndarray]:
         """Catch-up prefill of one or more freed rows into a live session
-        state: ONE masked forward feeds every row in ``rows`` its
-        ``seq[b, :seq_len[b]-1]`` (occupied rows ride along as no-ops) —
-        a group of slots joining the same model in one cycle costs one
-        insert, not one per row.
+        state: ONE forward over those rows alone feeds every row in
+        ``rows`` its ``seq[b, :seq_len[b]-1]`` (occupied rows are not
+        computed) — a group of slots joining the same model in one cycle
+        costs one insert, not one per row.
 
         Precondition: each row is already free (retire wiped it, or it
         has been masked-empty since the state was created).
 
-        Returns the (B, V) next-token distributions (rows outside
-        ``rows`` are garbage), or None when there was nothing to feed
-        (1-token prompts, or the capacity guard rebuilt the state — which
-        prefills the new rows too)."""
+        Returns the (k, V) next-token distributions of the rows fed, in
+        row order, or None when there was nothing to feed (1-token
+        prompts, or the capacity guard rebuilt the state — which prefills
+        the new rows too)."""
         B = seq.shape[0]
         sid = StateManager.key(m, session_id)
         rows = np.asarray(rows, bool)
@@ -370,7 +372,8 @@ class ChainRouter:
             tokens[b, :need[b]] = seq[b, done[b]:n[b]]
             valid[b, :need[b]] = True
         probs = self.executor.insert(InsertRequest(
-            model=m, request_id=session_id, tokens=tokens, valid=valid))
+            model=m, request_id=session_id, tokens=tokens, valid=valid,
+            rows=need > 0))
         self.profiler.count(f"admit.{m}", float(rows.sum()))
         return probs
 
@@ -383,9 +386,8 @@ class ChainRouter:
         row's (1, V) distribution for the similarity probe, or None."""
         rows = np.zeros(seq.shape[0], bool)
         rows[row] = True
-        probs = self._insert_rows(m, session_id, rows, seq, seq_len,
-                                  max_len, state_rows=state_rows)
-        return None if probs is None else probs[row:row + 1]
+        return self._insert_rows(m, session_id, rows, seq, seq_len,
+                                 max_len, state_rows=state_rows)
 
     def _sync_chain(self, chain: Tuple[str, ...], request_id: str,
                     needed: int, seq: np.ndarray, seq_len: np.ndarray,
@@ -756,9 +758,9 @@ class RouterSession:
                  (chain assigned;       (run_cycle() groups
                   catch-up prefill       active slots by chain
                   of the CHAIN's         and advances each
-                  models only; live      group in one masked
-                  rows are masked        sub-cycle)
-                  no-ops)
+                  models only, over      group in one masked
+                  the admitted row;      sub-cycle)
+                  live rows untouched)
 
     Chain membership is per-slot and LAZY: ``admit`` assigns the slot a
     chain (``get_optimal_chain(slot)`` seeded by the global prior, or an
@@ -868,7 +870,7 @@ class RouterSession:
                                      self.seq_len, self.max_len, rows=rows)
             mem[slot] = True
             r.profiler.count(f"admit.{m}")
-            return probs[slot:slot + 1]
+            return probs
         p = r._insert_row(m, self.session_id, slot, self.seq,
                           self.seq_len, self.max_len, state_rows=mem)
         mem[slot] = True
@@ -1016,9 +1018,10 @@ class RouterSession:
                 want.setdefault(m, np.zeros(B, bool))[s] = True
         probes: Dict[str, np.ndarray] = {}
         for m, rows in want.items():
-            probes[m] = r._prefill_model(m, self.session_id, self.seq,
-                                         self.seq_len, self.max_len,
-                                         rows=rows)
+            p = r._prefill_model(m, self.session_id, self.seq,
+                                 self.seq_len, self.max_len, rows=rows)
+            probes[m] = np.zeros((B, p.shape[-1]), p.dtype)
+            probes[m][rows] = p
             mem = self._members.setdefault(m, np.zeros(B, bool))
             mem |= rows
             r.profiler.count(f"admit.{m}", float(rows.sum()))

@@ -27,6 +27,7 @@ profiling path that refreshes the scheduler's per-op timings.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -58,6 +59,7 @@ class PrefillRequest:
     rollback_span: int = 0        # widest block a rollback may undo
     paged: bool = True            # paged KV state (archs that support it)
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    rows: Optional[np.ndarray] = None     # (B,) bool rows to feed; None=all
 
 
 @dataclasses.dataclass
@@ -150,12 +152,14 @@ class ResolveTreeRequest:
 @dataclasses.dataclass
 class InsertRequest:
     """Slot-level continuous batching: catch-up prefill of newly admitted
-    rows into an EXISTING batch state.  ``valid`` marks the admitted rows'
-    real tokens; live rows run as masked no-ops and are untouched."""
+    rows into an EXISTING batch state.  ``rows`` names the admitted rows
+    and ``valid`` their real tokens; the forward runs over those rows
+    alone, and every other row is untouched."""
     model: str
     request_id: str               # session id (state key namespace)
     tokens: np.ndarray            # (B, T) int32, left-aligned per row
     valid: np.ndarray             # (B, T) bool
+    rows: np.ndarray              # (B,) bool rows to feed
 
 
 @dataclasses.dataclass
@@ -436,12 +440,80 @@ class Executor:
             self._jit_cache[key] = jax.jit(s)
         return self._jit_cache[key]
 
-    # ---- processors ----------------------------------------------------
+    # ---- admission: forwards over the admitted rows ---------------------
+    def _admission(self, model: str, kind: str):
+        """The jitted admission forward of ``model`` (``kind`` "prefill" or
+        "insert"): gather the per-row leaves of rows ``idx`` (k,) into a
+        k-row state, run the forward there, scatter them back, and return
+        the k rows' next-token distributions.  Shared leaves (the paged KV
+        pool, the free stack) go in and come out whole.  Entries of ``idx``
+        equal to B pad k to its bucket: they read the first row with an
+        all-false mask and write nothing.  ``bax`` (static) is each leaf's
+        batch axis (``kvc.batch_axes``); None runs every row as one batch
+        and ignores ``idx``.  The state is donated, so the scatter and the
+        forward's cache writes update it in place."""
+        key = ("admission", model, kind)
+        if key not in self._jit_cache:
+            lm = self.pool.model(model)
+            fwd = lm.prefill if kind == "prefill" else lm.decode
+
+            def f(params, state, idx, tokens, valid, extras, bax):
+                sub = state
+                if bax is not None:
+                    take = jnp.where(idx < state.batch, idx, idx[0])
+                    sub = kvc.take_rows(state, take, bax)
+                    extras = jax.tree.map(lambda x: x[take], extras)
+                logits, sub = fwd(params, sub, tokens, valid=valid,
+                                  logits_mode="last", **extras)
+                if bax is not None:
+                    sub = kvc.put_rows(state, sub, idx, bax)
+                return jax.nn.softmax(logits.astype(jnp.float32), -1), sub
+
+            f.__name__ = f.__qualname__ = f"admission_{kind}"
+            self._jit_cache[key] = jax.jit(f, static_argnames=("bax",),
+                                           donate_argnames=("state",))
+        return self._jit_cache[key]
+
+    def _admit(self, req, kind: str, state, axes, extras=None):
+        """Run ``req``'s admission forward (``kind`` as ``_admission``) over
+        the rows ``req.rows`` marks (None: every row), their count bucketed
+        to a power of two.  A state whose rows cannot be told apart
+        (``kvc.batch_axes`` is None), or a bucket as large as the batch,
+        runs every row.  Returns the fed rows' (k, V) distributions in row
+        order and the new state."""
+        B, T = req.tokens.shape
+        sel = (np.arange(B) if req.rows is None
+               else np.flatnonzero(req.rows))
+        kb = 1
+        while kb < len(sel):          # pow-2 row buckets bound jit shapes
+            kb *= 2
+        bax = kvc.batch_axes(state, axes) if axes is not None else None
+        tokens, valid = req.tokens, req.valid
+        if bax is None or kb >= B:
+            bax, kb, idx = None, B, np.arange(B, dtype=np.int32)
+        else:
+            idx = np.full(kb, B, np.int32)
+            idx[:len(sel)] = sel
+            tokens = np.zeros((kb, T), np.int32)
+            valid = np.zeros((kb, T), bool)
+            tokens[:len(sel)] = req.tokens[sel]
+            valid[:len(sel)] = req.valid[sel]
+        self.profiler.count("admission_rows", float(kb))
+        prog = self._admission(req.model, kind)
+        with self.profiler.timed(kind, self._pq(req.model),
+                                 tokens=int(req.valid.sum())), self._mctx():
+            probs, state = prog(self.pool.params(req.model), state,
+                                jnp.asarray(idx), jnp.asarray(tokens),
+                                jnp.asarray(valid), extras or {}, bax=bax)
+            with self.profiler.wait():
+                probs = np.asarray(jax.block_until_ready(probs))
+        return (probs[sel] if bax is None else probs[:len(sel)]), state
+
     def prefill(self, req: PrefillRequest):
-        """PrefillProcessor: populate initial ModelState, return last-token
-        probs (used for similarity probes) and the state id."""
+        """PrefillProcessor: create the session's B-row state and feed the
+        rows ``req.rows`` marks.  Returns their (k, V) last-token probs
+        (similarity probes) and the state id."""
         lm = self.pool.model(req.model)
-        params = self.pool.params(req.model)
         sid = StateManager.key(req.model, req.request_id)
         B = req.tokens.shape[0]
         state, state_axes = lm.make_state(B, req.max_len,
@@ -455,44 +527,51 @@ class Executor:
                                                  state)
         if sharding is not None:
             state = jax.device_put(state, sharding)
-        key = ("prefillop", req.model, req.tokens.shape, req.paged)
-        if key not in self._jit_cache:
-            def f(params, state, tokens, valid, extras):
-                return lm.prefill(params, state, tokens, valid=valid,
-                                  logits_mode="last", **extras)
-            self._jit_cache[key] = jax.jit(f)
-        with self.profiler.timed("prefill", self._pq(req.model),
-                                 tokens=int(req.valid.sum())), self._mctx():
-            logits, state = self._jit_cache[key](
-                params, state, jnp.asarray(req.tokens),
-                jnp.asarray(req.valid), req.extras)
-            with self.profiler.wait():
-                logits = jax.block_until_ready(logits)
-        self.states.create(sid, state, layer_axes=state_axes.layers,
-                           sharding=sharding)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
-        return np.asarray(probs), sid
+        probs, state = self._admit(req, "prefill", state, state_axes,
+                                   req.extras)
+        self.states.create(sid, state, axes=state_axes, sharding=sharding)
+        return probs, sid
 
     def insert(self, req: InsertRequest):
         """InsertProcessor (continuous batching): feed the admitted rows'
         prompt tokens through the model against the live session state,
-        appending their KV/recurrent entries without disturbing occupied
-        slots.  Returns (B, V) probs at each row's last valid position —
-        the admitted row's distribution doubles as a similarity probe."""
-        params = self.pool.params(req.model)
+        appending their KV/recurrent entries; the forward runs over those
+        rows alone, so no other slot is touched.  Returns the admitted
+        rows' (k, V) probs at each one's last valid position — an admitted
+        row's distribution doubles as a similarity probe."""
         sid = StateManager.key(req.model, req.request_id)
-        state = self.states.get(sid)
-        fwd_last = self._fwd(req.model, "last")
-        with self.profiler.timed("insert", self._pq(req.model),
-                                 tokens=int(req.valid.sum())), self._mctx():
-            logits, state = fwd_last(params, state,
-                                     jnp.asarray(req.tokens),
-                                     jnp.asarray(req.valid), {})
-            with self.profiler.wait():
-                logits = jax.block_until_ready(logits)
-        self.states.update(sid, state)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
-        return np.asarray(probs)
+        axes = self.states.axes(sid)
+        with self._checked_out([sid]) as (state,):
+            probs, state = self._admit(req, "insert", state, axes)
+            self.states.commit([sid], [state])
+        return probs
+
+    @contextlib.contextmanager
+    def _checked_out(self, sids):
+        """Check states out of the registry for a program they are donated
+        to; the body commits the program's outputs.  If the body raises:
+        at trace time nothing executed and the states are still valid, so
+        they go back; after dispatch (e.g. device OOM) the donated buffers
+        are gone, and committing deleted arrays would poison every later
+        op with "Array has been deleted" errors, so the entries are
+        dropped and the next access fails cleanly.  try/finally, not a
+        broad except: nothing is swallowed, and KeyboardInterrupt /
+        SystemExit are covered too."""
+        states = self.states.checkout(sids)
+        ok = False
+        try:
+            yield states
+            ok = True
+        finally:
+            if not ok:
+                donated = any(
+                    getattr(leaf, "is_deleted", lambda: False)()
+                    for st in states for leaf in jax.tree.leaves(st))
+                if donated:
+                    for sid in sids:
+                        self.states.release(sid)
+                else:
+                    self.states.commit(sids, states)
 
     def retire(self, model: str, request_id: str, rows: np.ndarray):
         """RetireProcessor (continuous batching): free finished slot rows of
@@ -1008,36 +1087,13 @@ class Executor:
             prog = self._fused_program(req.chain, req.window, req.tree,
                                        req.greedy, req.temperature,
                                        req.prefix_width, req.eos)
-            states = self.states.checkout(sids)
             t0 = time.perf_counter()
-            ok = False
-            try:
-                with self._mctx():
-                    out = prog(params, tuple(states), req.seq, req.seq_len,
-                               req.prompt_len, req.budget, req.active,
-                               req.gmask, tuple(req.rngs))
-                ok = True
-            finally:
-                # try/finally, not a broad except: nothing is swallowed and
-                # the cleanup also covers KeyboardInterrupt/SystemExit.
-                # Trace-time failure: nothing executed, buffers still valid
-                # — restore them.  A RUNTIME failure after dispatch (e.g.
-                # device OOM) has already consumed the donated buffers;
-                # committing deleted arrays would poison every later op
-                # with confusing "Array has been deleted" errors, so drop
-                # the registry entries instead and let the next access
-                # fail cleanly.
-                if not ok:
-                    donated = any(
-                        getattr(leaf, "is_deleted", lambda: False)()
-                        for st in states for leaf in jax.tree.leaves(st))
-                    if donated:
-                        for sid in sids:
-                            self.states.release(sid)
-                    else:
-                        self.states.commit(sids, states)
-            new_states, seq, seq_len, active, summary = out
-            self.states.commit(sids, list(new_states))
+            with self._checked_out(sids) as states, self._mctx():
+                new_states, seq, seq_len, active, summary = prog(
+                    params, tuple(states), req.seq, req.seq_len,
+                    req.prompt_len, req.budget, req.active, req.gmask,
+                    tuple(req.rngs))
+                self.states.commit(sids, list(new_states))
         with self.profiler.wait():
             # speclint: disable=host-sync -- THE sanctioned one-transfer-
             # per-cycle FusedSummary device_get (counted by profiler.wait)

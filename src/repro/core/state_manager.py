@@ -13,8 +13,10 @@ Slot-level continuous batching: a serving session keys ONE batch-B state
 per model (``model/session_id``); individual batch rows are *slots* that
 are freed (``free_rows``) when a request finishes and re-filled by a
 catch-up prefill when a new request is admitted.  ``create`` optionally
-records the state's layer-axes pytree so ``free_rows`` can wipe recurrent
-per-row carries exactly (named ``"batch"`` axes), not heuristically.
+records the state's axes pytree (what ``make_state`` returns) so
+``free_rows`` can wipe recurrent per-row carries exactly (named
+``"batch"`` axes), not heuristically, and an admission can run its
+forward over the admitted rows alone (``axes``).
 
 Paged states (``PagedModelState``) free and account capacity in BLOCKS:
 ``free_rows`` returns a retired row's blocks to the pool in O(1) and
@@ -41,7 +43,7 @@ class StateManager:
         self.defrag_threshold = defrag_threshold
         self.defrag_count = 0
 
-    def create(self, state_id: str, state, layer_axes: Any = None,
+    def create(self, state_id: str, state, axes: Any = None,
                sharding: Any = None):
         """``sharding`` (a NamedSharding pytree from Placement) places the
         KV block pools / session buffers explicitly on creation; it is
@@ -52,8 +54,8 @@ class StateManager:
             state = jax.device_put(state, sharding)
         with self._lock:
             self._states[state_id] = state
-            if layer_axes is not None:
-                self._axes[state_id] = layer_axes
+            if axes is not None:
+                self._axes[state_id] = axes
             else:
                 self._axes.pop(state_id, None)
             if sharding is not None:
@@ -70,6 +72,11 @@ class StateManager:
     def get(self, state_id: str):
         with self._lock:
             return self._states[state_id]
+
+    def axes(self, state_id: str):
+        """The axes pytree a state was created with (None if none)."""
+        with self._lock:
+            return self._axes.get(state_id)
 
     def exists(self, state_id: str) -> bool:
         """Lazy chain membership: a model outside every live slot's chain
@@ -119,8 +126,9 @@ class StateManager:
         happen under the registry lock."""
         with self._lock:
             st = self._states[state_id]
-            self._states[state_id] = _free_rows(st, rows,
-                                                self._axes.get(state_id))
+            axes = self._axes.get(state_id)
+            self._states[state_id] = _free_rows(
+                st, rows, axes.layers if axes is not None else None)
 
     def maybe_defragment(self, state_id: str, force: bool = False) -> bool:
         """Beyond-paper: compact masked holes when fragmentation is high

@@ -285,6 +285,49 @@ def free_rows(state, rows, layer_axes=None):
         new, layers=jax.tree.unflatten(treedef, new_leaves))
 
 
+# ---------------------------------------------------------------------------
+# Row-scoped views: a forward over some rows of a state
+# ---------------------------------------------------------------------------
+def batch_axes(state, state_axes) -> Optional[tuple]:
+    """Each leaf's ``"batch"`` axis, in ``jax.tree.leaves(state)`` order,
+    from the axes ``make_state`` returned with it; -1 for a leaf every row
+    shares (the paged block pool and its free stack, a contiguous state's
+    write pointer).  None when some leaf declares no axes at all (snapshot
+    rings), so its rows cannot be told apart."""
+    leaves, treedef = jax.tree.flatten(state)
+    out = []
+    for ax in treedef.flatten_up_to(state_axes):
+        if not isinstance(ax, tuple):
+            return None
+        out.append(ax.index("batch") if "batch" in ax else -1)
+    return tuple(out)
+
+
+def take_rows(state, rows: jnp.ndarray, bax: tuple):
+    """The state of rows ``rows`` (k,) alone: every per-row leaf gathered
+    along its batch axis (``bax``, from ``batch_axes``), every shared leaf
+    whole.  Out-of-range rows read the last row."""
+    leaves, treedef = jax.tree.flatten(state)
+    return jax.tree.unflatten(treedef, [
+        x if a < 0 else jnp.take(x, rows, axis=a, mode="clip")
+        for x, a in zip(leaves, bax)])
+
+
+def put_rows(state, sub, rows: jnp.ndarray, bax: tuple):
+    """Write ``sub`` (a ``take_rows`` state, run forward) back into rows
+    ``rows`` of ``state``; its shared leaves replace the state's whole.
+    Out-of-range rows (padding) write nothing."""
+    leaves, treedef = jax.tree.flatten(state)
+    new = jax.tree.leaves(sub)
+
+    def put(x, y, a):
+        if a < 0:
+            return y
+        return x.at[(slice(None),) * a + (rows,)].set(y, mode="drop")
+    return jax.tree.unflatten(treedef, [
+        put(x, y, a) for x, y, a in zip(leaves, new, bax)])
+
+
 def fragmentation(state) -> jnp.ndarray:
     """Fraction of physically-used slots that are logically dead."""
     if isinstance(state, PagedModelState):

@@ -8,9 +8,10 @@ of ``batch_size`` slots, per-slot request lifecycle
     QUEUED -> PREFILL -> DECODING -> DONE
 
 New requests are admitted into freed slots *between* speculation cycles
-(RouterSession.admit catch-up-prefills the new row while live rows run as
-masked no-ops) and finished rows retire without stalling the others, so a
-long request never blocks the arrivals queued behind it.  This is the
+(RouterSession.admit catch-up-prefills the new row in a forward over that
+row alone; live rows are untouched) and finished rows retire without
+stalling the others, so a long request never blocks the arrivals queued
+behind it.  This is the
 iteration-level scheduling that SLO-aware serving systems (SpecServe,
 StreamServe) identify as the main goodput/p95-TTFT lever under load.
 Routing is per-slot with lazy chain membership (see core/chain_router.py):
