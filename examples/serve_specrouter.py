@@ -7,11 +7,8 @@ baselines for the EAF speedup.
         [--dataset gsm8k] [--rate 0.5] [--duration 20] [--batch 4] \
         [--tree 2x2x1]      # token-tree speculation (SSD-Tree baseline +
                             # the shape joins SpecRouter's search space)
-        [--no-continuous]   # legacy stop-the-world batch formation
-        [--no-paged]        # legacy contiguous shared-pointer KV (A/B)
-        [--no-slot-routing] # legacy global-chain routing: one chain per
-                            # cycle, whole pool prefilled at admission
-        [--no-fused]        # legacy host-orchestrated per-op cycles (A/B)
+        [--no-paged]        # contiguous shared-pointer KV state
+        [--no-fused]        # host-orchestrated per-op cycles
         [--profile-every N] # unfused profiling-cycle cadence (default 16)
         [--workload burst]  # MMPP bursty arrivals instead of Poisson
         [--workload trace --trace-file t.jsonl]  # JSONL trace replay
@@ -57,7 +54,6 @@ def build_requests(corpus, args):
 
 def run(pool, corpus, args, label, router_kwargs):
     router_kwargs = dict(router_kwargs, paged=not args.no_paged,
-                         slot_routing=not args.no_slot_routing,
                          fused=not args.no_fused,
                          profile_every=args.profile_every)
     reqs = build_requests(corpus, args)
@@ -65,7 +61,6 @@ def run(pool, corpus, args, label, router_kwargs):
                         slo_latency_s=args.slo,
                         shed_policy="ttft" if args.shed else "none",
                         router_kwargs=router_kwargs,
-                        continuous=not args.no_continuous,
                         mesh=args.mesh)
     m = eng.run(reqs)
     line = (f"[{label:<22}] goodput {m.goodput_tps:7.1f} tok/s | "
@@ -93,19 +88,13 @@ def main():
                     help="token-tree speculation shape, e.g. 2x2x1: adds "
                          "an SSD-Tree static baseline and lets the "
                          "adaptive scheduler pick the tree draft")
-    ap.add_argument("--no-continuous", action="store_true",
-                    help="legacy stop-the-world batch formation (A/B)")
     ap.add_argument("--no-paged", action="store_true",
-                    help="legacy contiguous shared-pointer KV state "
-                         "instead of the paged per-slot block tables (A/B)")
-    ap.add_argument("--no-slot-routing", action="store_true",
-                    help="legacy global-chain routing — one chain for "
-                         "every slot per cycle and O(pool) admission "
-                         "prefill — instead of per-slot lazy chains (A/B)")
+                    help="contiguous shared-pointer KV state instead of "
+                         "the paged per-slot block tables")
     ap.add_argument("--no-fused", action="store_true",
-                    help="legacy host-orchestrated per-op speculation "
-                         "cycles instead of the device-resident fused "
-                         "cycle program (A/B)")
+                    help="host-orchestrated per-op speculation cycles "
+                         "instead of the device-resident fused cycle "
+                         "program")
     ap.add_argument("--profile-every", type=int, default=16,
                     help="run an unfused profiling cycle every N cycles "
                          "to refresh the scheduler's per-op timings "

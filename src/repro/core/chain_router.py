@@ -26,7 +26,7 @@ row terminates.  Slots are batch rows of ONE per-model session state
 (key ``model/session_id``), so admission/retirement is per-row state
 surgery (Executor.insert / Executor.retire), not state re-creation.
 
-Per-slot chain routing with LAZY chain membership (default): every slot
+Per-slot chain routing with LAZY chain membership: every slot
 carries its own ``ChainChoice`` — the admission-time similarity probe and
 the slot's per-row verify feedback drive ``get_optimal_chain(slot)`` with
 the global Eq. 7 memo as the shared prior — and a slot materializes state
@@ -37,9 +37,8 @@ joining a slot's chain later catches up through the ``_insert_row`` path
 slots by assigned (chain, window, tree) and runs one active-masked
 sub-cycle per group, so every jitted shape stays static and greedy output
 remains bit-exact to target-only decoding per slot regardless of
-grouping.  ``slot_routing=False`` restores the legacy behaviour — one
-global chain per cycle, every pool model prefilled at admission — as the
-A/B baseline (``benchmarks/routing_ab.py``).
+grouping (``test_admission_touches_only_chain_members`` and
+``test_two_slots_different_chains_bit_exact`` pin both).
 
 Device-resident cycles (default, ``fused=True``): each sub-cycle group
 runs as ONE jitted program (``Executor.fused_cycle``) that keeps the
@@ -49,12 +48,17 @@ member's model state on device; only a small per-cycle ``FusedSummary``
 one transfer, and the host mirror of ``seq``/``seq_len``/``active`` is
 rebuilt from it exactly (``generated``/``retire`` read the mirror).
 Because fusing hides per-op timings, every ``profile_every``-th cycle
-(default 16, cycle 0 included) runs the legacy per-op path instead,
+(default 16, cycle 0 included) runs the per-op path instead,
 refreshing the scheduler's ``T_i`` EMAs; capacity pressure or an
 oversized catch-up gap also falls back to the per-op path for that cycle
-(it owns the defrag/re-prefill escapes).  ``fused=False`` keeps the
-host-orchestrated loop everywhere — the bit-exact A/B baseline
-(``benchmarks/cycle_overhead.py``).
+(it owns the defrag/re-prefill escapes).  ``fused=False`` runs the
+host-orchestrated loop everywhere, bit-exact to the fused one
+(``tests/test_fused_cycle.py`` pins the output and the host syncs).
+
+The serving engine (``serving/engine.py``) drives one session through
+``admit`` / ``run_cycle`` / ``retire``; the benchmark measures that path
+(``python3 bench/run.py --workload <cell>``, numbers in
+``PERF_LEDGER.jsonl``).
 """
 from __future__ import annotations
 
@@ -88,10 +92,6 @@ class GenerationResult:
     committed_tokens: int
     chain_history: List[Tuple[Tuple[str, ...], int]]
     acceptance_lengths: List[float]  # mean accepted per cycle (diagnostics)
-    prefill_wall_s: float = 0.0
-    cycle_wall_s: List[float] = dataclasses.field(default_factory=list)
-    commits_per_cycle: List[np.ndarray] = dataclasses.field(
-        default_factory=list)     # (B,) per cycle
 
 
 @dataclasses.dataclass
@@ -128,7 +128,6 @@ class ChainRouter:
                  fixed_tree=None,
                  seed: int = 0,
                  paged: bool = True,
-                 slot_routing: bool = True,
                  fused: bool = True,
                  profile_every: int = 16,
                  scheduler_kwargs: Optional[dict] = None,
@@ -139,20 +138,14 @@ class ChainRouter:
         # program, with periodic unfused profiling cycles every
         # ``profile_every`` steps (0 = never; when enabled, cycle 0 is a
         # profiling cycle so the scheduler starts with real per-op
-        # timings).  ``fused=False`` keeps the host-orchestrated per-op
-        # loop everywhere as the A/B baseline.
+        # timings).  ``fused=False`` runs the host-orchestrated per-op
+        # loop everywhere.
         self.fused = fused
         self.profile_every = int(profile_every)
-        # per-slot chain routing + lazy chain membership (the default):
-        # each slot is scheduled independently and holds state only in
-        # its assigned chain's models.  ``slot_routing=False`` keeps the
-        # legacy one-global-chain engine that prefills the WHOLE pool at
-        # admission — the O(pool)-admission baseline for A/B.
-        self.slot_routing = slot_routing
         # paged KV cache (per-slot block tables) is the default serving
-        # state; ``paged=False`` keeps the legacy contiguous shared-pointer
-        # state for A/B.  Archs without a per-position cache (SSM/hybrid)
-        # fall back to contiguous automatically either way.
+        # state; ``paged=False`` keeps the contiguous shared-pointer
+        # state.  Archs without a per-position cache (SSM/hybrid) fall
+        # back to contiguous automatically either way.
         self.paged = paged
         self.eos = eos_token
         self.greedy = greedy
@@ -465,13 +458,13 @@ class ChainRouter:
         seq[rows, base + kr] = np.asarray(next_token)[rows]
         seq_len[rows] += kr + 1
 
-    def _observe_slots(self, slot_keys: Optional[Sequence[str]],
+    def _observe_slots(self, slot_keys: Sequence[str],
                        producer: str, verifier: str, dtv: np.ndarray,
                        active: np.ndarray) -> None:
         """Per-slot acceptance feedback: each active row's verify DTV
         updates that slot's similarity view (the per-slot scheduler's
         evidence), alongside the pool-global EMA."""
-        if slot_keys is None or not self.adaptive:
+        if not self.adaptive:
             return
         for b in np.where(active)[0]:
             self.scheduler.observe_slot(slot_keys[b], producer, verifier,
@@ -508,15 +501,11 @@ class ChainRouter:
         sess.budget[:] = budget
         sess.occupied[:] = True
         sess.active[:] = True
-        t0 = _time.perf_counter()
         sess.boot()
-        prefill_wall = _time.perf_counter() - t0
 
-        acc_lens, cycle_wall, commits_hist = [], [], []
+        acc_lens = []
         while sess.active.any() and sess.committed < max_new * B:
             rep = sess.run_cycle()
-            cycle_wall.append(rep.wall_s)
-            commits_hist.append(rep.commits.copy())
             acc_lens.append(rep.acc_mean)
             if sess.steps > max_new * 4 + 16:   # safety net
                 break
@@ -529,18 +518,15 @@ class ChainRouter:
         sess.close()
         return GenerationResult(seqs, gens, steps,
                                 int(sum(len(g) for g in gens)),
-                                hist, acc_lens,
-                                prefill_wall_s=prefill_wall,
-                                cycle_wall_s=cycle_wall,
-                                commits_per_cycle=commits_hist)
+                                hist, acc_lens)
 
     # ------------------------------------------------------------------
     def _one_cycle(self, chain: Tuple[str, ...], W: int, request_id: str,
                    seq: np.ndarray, seq_len: np.ndarray,
-                   active: np.ndarray,
+                   active: np.ndarray, slot_keys: Sequence[str],
                    tree: Optional[TokenTree] = None,
-                   members: Optional[Dict[str, np.ndarray]] = None,
-                   slot_keys: Optional[Sequence[str]] = None) -> np.ndarray:
+                   members: Optional[Dict[str, np.ndarray]] = None
+                   ) -> np.ndarray:
         """Execute one speculative cycle; mutates seq/seq_len in place.
         Returns per-row committed token count.  A non-None ``tree`` routes
         the cycle through tree-structured speculation (draft a token tree,
@@ -550,8 +536,8 @@ class ChainRouter:
         scheduler views."""
         if tree is not None and len(chain) > 1:
             return self._one_tree_cycle(chain, tree, request_id, seq,
-                                        seq_len, active, members=members,
-                                        slot_keys=slot_keys)
+                                        seq_len, active, slot_keys,
+                                        members=members)
         B = seq.shape[0]
         max_len = self.states.get(
             StateManager.key(self.target, request_id)).capacity
@@ -649,9 +635,8 @@ class ChainRouter:
     def _one_tree_cycle(self, chain: Tuple[str, ...], tree: TokenTree,
                         request_id: str, seq: np.ndarray,
                         seq_len: np.ndarray,
-                        active: np.ndarray,
-                        members: Optional[Dict[str, np.ndarray]] = None,
-                        slot_keys: Optional[Sequence[str]] = None
+                        active: np.ndarray, slot_keys: Sequence[str],
+                        members: Optional[Dict[str, np.ndarray]] = None
                         ) -> np.ndarray:
         """One tree-structured speculative cycle (SpecInfer-style):
 
@@ -769,9 +754,7 @@ class RouterSession:
     reassign the chain later: leaving models free the slot's row
     immediately, joining models catch up through ``_insert_row`` (priced
     by the scheduler's switch penalty).  ``retire`` frees exactly the
-    member rows.  With ``router.slot_routing=False`` the legacy behaviour
-    is preserved: one global chain per cycle and every pool model
-    materialized at admission (the O(pool) A/B baseline).
+    member rows.
     """
 
     def __init__(self, router: ChainRouter, num_slots: int, max_len: int,
@@ -799,7 +782,6 @@ class RouterSession:
         self._members: Dict[str, np.ndarray] = {}
         self._slot_choice: List[Optional[ChainChoice]] = [None] * B
         self._forced: np.ndarray = np.zeros(B, bool)  # admit(chain=...)
-        self._global_choice: Optional[ChainChoice] = None  # legacy engine
         # device-resident session buffers (fused cycles): the numpy arrays
         # above are the HOST MIRROR, rebuilt exactly from each fused
         # cycle's summary slab; ``_dev`` holds the authoritative device
@@ -830,16 +812,7 @@ class RouterSession:
         r = self.router
         if r.fixed_chain is not None:
             return self._fixed_choice()
-        if not r.slot_routing:
-            return r.scheduler.get_optimal_chain()
         return r.scheduler.get_optimal_chain(slot=self._skey(slot))
-
-    def _admit_models(self, chain: Tuple[str, ...]) -> Tuple[str, ...]:
-        """Which models an admission materializes: the slot's chain
-        (lazy membership) or the whole pool (legacy baseline)."""
-        if self.router.slot_routing:
-            return chain
-        return tuple(self.router.pool.names())
 
     # ---- membership surgery -------------------------------------------
     def _invalidate_state_caches(self) -> None:
@@ -932,9 +905,9 @@ class RouterSession:
               tpot_slo_s: Optional[float] = None) -> float:
         """Admit a request into a free slot (QUEUED -> PREFILL): assign
         the slot a chain, write its prompt into the slot row, and
-        catch-up-prefill the CHAIN members only (the whole pool when
-        ``router.slot_routing=False``).  An explicit ``chain``/``window``/
-        ``tree`` pins the slot's routing (bypassing the scheduler).
+        catch-up-prefill the CHAIN members only.  An explicit
+        ``chain``/``window``/``tree`` pins the slot's routing (bypassing
+        the scheduler).
         ``ttft_slo_s``/``tpot_slo_s`` attach the request's SLOs to the
         slot's chain search (the goodput objective's per-slot inputs;
         cleared at retirement).
@@ -987,14 +960,14 @@ class RouterSession:
         self._slot_choice[slot] = choice
         self._forced[slot] = chain is not None
         probe: Dict[str, np.ndarray] = {}
-        for m in self._admit_models(choice.chain):
+        for m in choice.chain:
             p = self._materialize_row(m, slot)
             if p is not None:
                 probe[m] = p
         if len(probe) >= 2:   # admission doubles as a similarity probe
             dtvs = pairwise_dtv(probe)
             r.sims.update_many(dtvs)
-            if r.slot_routing and r.adaptive:
+            if r.adaptive:
                 for (a, b), v in dtvs.items():
                     r.scheduler.observe_slot(self._skey(slot), a, b, v)
         return _time.perf_counter() - t0
@@ -1014,7 +987,7 @@ class RouterSession:
                 self._slot_choice[s] = self._choose(int(s))
         want: Dict[str, np.ndarray] = {}
         for s in occ:
-            for m in self._admit_models(self._slot_choice[s].chain):
+            for m in self._slot_choice[s].chain:
                 want.setdefault(m, np.zeros(B, bool))[s] = True
         probes: Dict[str, np.ndarray] = {}
         for m, rows in want.items():
@@ -1030,7 +1003,7 @@ class RouterSession:
             if not rows.any():
                 continue
             r.sims.update(a, b, float(np.mean(v[rows])))
-            if r.slot_routing and r.adaptive:
+            if r.adaptive:
                 for s in np.where(rows)[0]:
                     r.scheduler.observe_slot(self._skey(int(s)), a, b,
                                              float(v[s]))
@@ -1045,19 +1018,6 @@ class RouterSession:
                     self._slot_choice[s] = self._fixed_choice()
             return
         resched = r.adaptive and self.steps % r.reschedule_every == 0
-        if not r.slot_routing:
-            # legacy-engine fidelity: ONE shared global chain per cycle
-            # for every (non-pinned) slot, refreshed on the reschedule
-            # cadence — slots admitted mid-interval must not capture a
-            # drifted global choice and split the cycle into groups.
-            # Membership stays materialized across switches, exactly like
-            # the old engine (laggards catch up through the gap path).
-            if self._global_choice is None or resched:
-                self._global_choice = r.scheduler.get_optimal_chain()
-            for s in np.where(self.active)[0]:
-                if not self._forced[s]:
-                    self._slot_choice[s] = self._global_choice
-            return
         for s in np.where(self.active)[0]:
             cur = self._slot_choice[s]
             if cur is not None and (self._forced[s] or not resched):
@@ -1155,7 +1115,7 @@ class RouterSession:
         program: (the program's request, None), or (None, why the group
         falls back to the per-op path this cycle: ``untimed``, ``gap`` —
         a catch-up gap wider than the program's static prefix — or
-        ``capacity``; the last two are the legacy path's escape
+        ``capacity``; the last two are the per-op path's escape
         hatches)."""
         r = self.router
         chain = choice.chain
@@ -1166,8 +1126,7 @@ class RouterSession:
         # T_i measurements, so without this the scheduler could keep
         # exploring a slow chain forever between profiling cycles — the
         # first cycle of any new chain doubles as its profiling cycle
-        # (benchmarks/routing_ab.py pins the resulting decoy-kill
-        # behaviour under the fused default)
+        # (tests/test_profiler_spans.py::test_fallback_counters_by_reason)
         if not self._chain_timed(chain, tree):
             return None, "untimed"
         depth = tree.depth_levels if tree is not None else choice.window
@@ -1209,7 +1168,7 @@ class RouterSession:
 
     def _run_fused_group(self, req: FusedCycleRequest, choice: ChainChoice,
                          gmask: np.ndarray,
-                         slot_keys: Optional[Sequence[str]]) -> np.ndarray:
+                         slot_keys: Sequence[str]) -> np.ndarray:
         """Run one sub-cycle group as a single device program (``req``
         from ``_prepare_fused``).  Returns per-row raw commits."""
         r = self.router
@@ -1250,7 +1209,7 @@ class RouterSession:
                              float(np.sum(run & (kept < tc))))
 
     def _mirror_summary(self, choice: ChainChoice, gmask: np.ndarray,
-                        slot_keys: Optional[Sequence[str]], bufs,
+                        slot_keys: Sequence[str], bufs,
                         s) -> np.ndarray:
         """Host mirror of one fused group's summary, and the similarity
         and acceptance feedback; returns per-row raw commits."""
@@ -1332,8 +1291,7 @@ class RouterSession:
                         groups[key] = np.zeros(B, bool)
                         order.append(key)
                     groups[key][s] = True
-                slot_keys = ([self._skey(s) for s in range(B)]
-                             if r.slot_routing else None)
+                slot_keys = [self._skey(s) for s in range(B)]
             pre_active = self.active.copy()
             gen_before = (self.seq_len - self.prompt_len).copy()
             n_acc = np.zeros(B, np.int64)
@@ -1369,10 +1327,9 @@ class RouterSession:
                     with prof.span("cycle.per_op", chain=chain_name):
                         acc = r._one_cycle(choice.chain, choice.window,
                                            self.session_id, self.seq,
-                                           self.seq_len, gmask,
+                                           self.seq_len, gmask, slot_keys,
                                            tree=choice.tree,
-                                           members=self._members,
-                                           slot_keys=slot_keys)
+                                           members=self._members)
                     # the per-op path mutated host state directly: device
                     # buffers and summary-fed cursor views are stale (a
                     # later fused group this cycle must re-upload)

@@ -96,8 +96,9 @@ class PerformanceProfiler:
 
     The ``host_sync`` counter tallies host-synchronizing waits on the
     device, each inside a ``cycle.wait`` span (``wait()``): one per per-op
-    processor call on the legacy path, ONE per cycle group on the fused
-    path — ``benchmarks/cycle_overhead.py`` asserts the gap.
+    processor call on the per-op path, ONE per cycle group on the fused
+    path — ``tests/test_fused_cycle.py::test_fused_fewer_host_syncs``
+    pins the gap.
 
     Spans (``span(name, **args)``) have fixed names; what varies (model,
     chain, request id) goes in ``args``, which reach the trace as the

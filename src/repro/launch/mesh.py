@@ -1,4 +1,4 @@
-"""Production mesh construction and the per-chip peak table (deliverable e).
+"""Production mesh construction and virtual CPU devices (deliverable e).
 
 Functions, not module-level constants — importing this module never
 touches jax device state (required because the dry-run sets
@@ -8,7 +8,6 @@ while tests/benches must see the single real CPU device).
 from __future__ import annotations
 
 import os
-from typing import Dict, NamedTuple
 
 import jax
 from jax.sharding import AxisType
@@ -34,30 +33,4 @@ def request_cpu_devices(n: int) -> None:
             "XLA_FLAGS", ""):
         return
     jax.config.update("jax_num_cpu_devices", n)
-
-
-class ChipPeaks(NamedTuple):
-    flops_bf16: float          # FLOP/s
-    hbm_bw: float              # B/s
-    ici_bw_per_link: float     # B/s per link
-    source: str
-
-
-# Keyed by ``jax.Device.device_kind``.
-PEAKS: Dict[str, ChipPeaks] = {
-    "TPU v5 lite": ChipPeaks(
-        flops_bf16=197e12, hbm_bw=819e9, ici_bw_per_link=50e9,
-        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
-               "819 GB/s HBM, 1,600 Gbit/s ICI per chip (4 links)"),
-}
-
-
-def chip_peaks(device_kind: str) -> ChipPeaks:
-    """Published peaks for ``device_kind``; an unknown kind is an error,
-    never a default."""
-    try:
-        return PEAKS[device_kind]
-    except KeyError:
-        raise KeyError(f"no published peaks for device kind {device_kind!r} "
-                       f"(known: {sorted(PEAKS)})") from None
 

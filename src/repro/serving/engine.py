@@ -2,8 +2,8 @@
 ChainRouter and collects the paper's §5 metrics (goodput, request
 throughput, TTFT, TPOT, EAF, SLO attainment).
 
-Batching model (default): **slot-level continuous batching** — a fixed pool
-of ``batch_size`` slots, per-slot request lifecycle
+Batching model: **slot-level continuous batching** — a fixed pool of
+``batch_size`` slots, per-slot request lifecycle
 
     QUEUED -> PREFILL -> DECODING -> DONE
 
@@ -17,18 +17,19 @@ StreamServe) identify as the main goodput/p95-TTFT lever under load.
 Routing is per-slot with lazy chain membership (see core/chain_router.py):
 admission materializes a request only in its assigned chain's models —
 O(chain) prefill work and KV footprint, not O(pool) — and each cycle runs
-one masked sub-cycle per distinct (chain, window, tree) group.  Pass
-``router_kwargs=dict(slot_routing=False)`` for the legacy global-chain
-baseline (``benchmarks/routing_ab.py`` is the A/B).
+one masked sub-cycle per distinct (chain, window, tree) group
+(``test_admission_touches_only_chain_members`` pins the admission
+counters).
 
-Speculation cycles are DEVICE-RESIDENT by default (``fused=True``): each
-sub-cycle group is one jitted program and one host transfer per cycle,
-with periodic unfused profiling cycles (``router_kwargs["profile_every"]``,
-default 16) refreshing the scheduler's per-op timings; ``fused=False``
-restores the per-op host-orchestrated loop
-(``benchmarks/cycle_overhead.py`` is the A/B).
+Speculation cycles are DEVICE-RESIDENT by default
+(``router_kwargs["fused"]``): each sub-cycle group is one jitted program
+and one host transfer per cycle, with periodic unfused profiling cycles
+(``router_kwargs["profile_every"]``, default 16) refreshing the
+scheduler's per-op timings; ``fused=False`` runs the per-op
+host-orchestrated loop (``tests/test_fused_cycle.py`` pins the host-sync
+count of both).
 
-SLO-aware serving (continuous mode): every request may carry a TTFT/TPOT
+SLO-aware serving: every request may carry a TTFT/TPOT
 SLO (``data/workload.py``; engine-level ``ttft_slo_s``/``tpot_slo_s``
 fill unset ones).  When any SLO is configured the scheduler's objective
 switches from raw T_eff to predicted SLO attainment — the engine
@@ -40,15 +41,12 @@ speculation.  Admission becomes earliest-TTFT-deadline-first (exact FIFO
 for no-SLO populations), and ``shed_policy="ttft"`` drops queued
 requests whose deadline is already unmeetable.  With no SLOs configured
 everything degenerates to the latency-only scheduler bit-exactly
-(``tests/test_slo_scheduling.py`` pins this; ``benchmarks/goodput_ab.py``
-is the A/B).
+(``tests/test_slo_scheduling.py`` pins this).
 
-Legacy model (``continuous=False``): stop-the-world batch formation —
-requests queue until ``batch_size`` are available (or ``batch_wait_s``
-elapses), then the batch generates to completion.  Kept as the reproducible
-A/B baseline (``benchmarks/run.py --no-continuous``).
+The benchmark drives this engine (``python3 bench/run.py --workload
+<cell>``); its numbers are in ``PERF_LEDGER.jsonl``.
 
-Timing semantics (both modes): arrivals follow the workload trace on a
+Timing semantics: arrivals follow the workload trace on a
 simulated clock; service time is the REAL wall time of the host models.
 Queueing delay is fully billed to TTFT — a request's first-token clock
 starts at ``arrival_s``, and every admission prefill / speculation cycle
@@ -91,7 +89,7 @@ class ServingMetrics:
     num_shed: int = 0               # dropped by the admission shed policy
     # mean host syncs of the cycles that ran every group as one fused
     # device program (the one-transfer contract: 1 per group); NaN when
-    # no cycle was fused (legacy engine, fused=False)
+    # no cycle was fused (fused=False)
     fused_cycle_host_syncs: float = float("nan")
 
     def as_dict(self) -> Dict[str, float]:
@@ -100,12 +98,9 @@ class ServingMetrics:
 
 class ServingEngine:
     def __init__(self, pool: ModelPool, target: str,
-                 batch_size: int = 4, batch_wait_s: float = 0.25,
+                 batch_size: int = 4,
                  slo_latency_s: float = 30.0,
                  router_kwargs: Optional[dict] = None,
-                 continuous: bool = True,
-                 paged: Optional[bool] = None,
-                 fused: Optional[bool] = None,
                  ttft_slo_s: Optional[float] = None,
                  tpot_slo_s: Optional[float] = None,
                  slo_aware: Optional[bool] = None,
@@ -134,11 +129,9 @@ class ServingEngine:
                 raise ValueError(
                     f"pool is already placed on {pool.placement.describe()}"
                     f", cannot re-place on {placement.describe()}")
-        self.batch_size = batch_size       # slot count in continuous mode
-        self.batch_wait_s = batch_wait_s   # legacy batch-formation window
+        self.batch_size = batch_size       # slot count
         self.slo = slo_latency_s
-        self.continuous = continuous
-        # --- SLO-aware serving (continuous mode) ------------------------
+        # --- SLO-aware serving ------------------------------------------
         # ``ttft_slo_s``/``tpot_slo_s`` fill in for requests that carry no
         # SLO of their own (per-request SLOs always win).  ``slo_aware``
         # switches the scheduler's objective to goodput (None = auto:
@@ -153,10 +146,6 @@ class ServingEngine:
                              "(expected 'none' or 'ttft')")
         self.shed_policy = shed_policy
         self.router_kwargs = dict(router_kwargs or {})
-        if paged is not None:              # engine-level A/B convenience
-            self.router_kwargs.setdefault("paged", paged)
-        if fused is not None:              # device-resident cycles A/B
-            self.router_kwargs.setdefault("fused", fused)
         # one router per engine: jit caches and scheduler state persist
         # across batches (recompiling per batch would bill compilation to
         # every request's latency)
@@ -178,18 +167,15 @@ class ServingEngine:
                       for r in reqs)
         # goodput objective: auto-activates when any request carries an
         # SLO; ``slo_aware=False`` forces the latency-only argmin even
-        # then (the A/B baseline in benchmarks/goodput_ab.py)
+        # then
         self._router.scheduler.slo_aware = (
             self.slo_aware if self.slo_aware is not None else has_slo)
         self._fused_syncs = []
-        if self.continuous:
-            acc_lens = self._run_continuous(reqs)
-        else:
-            acc_lens = self._run_legacy(reqs)
+        acc_lens = self._run_continuous(reqs)
         return self._metrics(reqs, acc_lens)
 
     # ------------------------------------------------------------------
-    # continuous mode: slot-level admission / retirement
+    # slot-level admission / retirement
     # ------------------------------------------------------------------
     def _run_continuous(self, reqs: List[Request]) -> List[float]:
         B = self.batch_size
@@ -332,70 +318,6 @@ class ServingEngine:
             cycle_ema_s=self._router.profiler.cycle_time(),
             num_slots=B))
         return clock, i, queue
-
-    # ------------------------------------------------------------------
-    # legacy mode: stop-the-world batch formation (A/B baseline)
-    # ------------------------------------------------------------------
-    def _run_legacy(self, reqs: List[Request]) -> List[float]:
-        clock = 0.0
-        i = 0
-        batch_no = 0
-        acc_lens: List[float] = []
-        while i < len(reqs):
-            batch = [reqs[i]]
-            i += 1
-            # batch formation: wait for up to batch_size or batch_wait_s
-            window_end = max(clock, batch[0].arrival_s) + self.batch_wait_s
-            while (i < len(reqs) and len(batch) < self.batch_size
-                   and reqs[i].arrival_s <= window_end):
-                batch.append(reqs[i])
-                i += 1
-            start = max(clock, max(r.arrival_s for r in batch))
-            acc = self._serve_batch(batch, start, f"batch{batch_no}")
-            batch_no += 1
-            acc_lens.extend(acc)
-            clock = max(r.finish_s for r in batch)
-        return acc_lens
-
-    def _serve_batch(self, batch: List[Request], start: float,
-                     batch_key: str) -> List[float]:
-        B = len(batch)
-        maxlen = max(len(r.prompt) for r in batch)
-        prompt = np.zeros((B, maxlen), np.int64)
-        lens = np.zeros(B, np.int64)
-        for b, r in enumerate(batch):
-            prompt[b, :len(r.prompt)] = r.prompt
-            lens[b] = len(r.prompt)
-            r.start_s = start
-        budgets = np.array([r.max_new_tokens for r in batch])
-
-        # state keys are namespaced by the batch, not by any single
-        # request's id: each slot row of the batch state is distinct and
-        # two batches can never collide on a shared request id
-        res = self._router.generate(prompt, lens, max_new_tokens=budgets,
-                                    request_id=batch_key)
-
-        # reconstruct per-request timing from per-cycle commits
-        t = start + res.prefill_wall_s
-        cum = np.zeros(B, np.int64)
-        first_at = np.full(B, -1.0)
-        done_at = np.full(B, -1.0)
-        gen_len = np.array([len(g) for g in res.generated])
-        for wall, commits in zip(res.cycle_wall_s, res.commits_per_cycle):
-            t += wall
-            newly = (cum == 0) & (commits > 0)
-            first_at[newly] = t
-            cum += commits
-            fin = (done_at < 0) & (cum >= np.minimum(budgets, gen_len))
-            done_at[fin] = t
-        done_at[done_at < 0] = t
-        first_at[first_at < 0] = t
-        for b, r in enumerate(batch):
-            r.first_token_s = first_at[b]
-            r.finish_s = done_at[b]
-            r.generated = int(gen_len[b])
-            r.output_tokens = res.generated[b]
-        return res.acceptance_lengths
 
     # ------------------------------------------------------------------
     def _metrics(self, reqs: List[Request],

@@ -86,7 +86,7 @@ def test_fused_bit_exact(pool, reference, chain, window, tree):
 def test_fused_fewer_host_syncs(pool, reference):
     """The fused path's host-sync count per cycle must be strictly below
     the per-op path on the same workload (the one-transfer-per-cycle
-    contract; benchmarks/cycle_overhead.py asserts the same in CI)."""
+    contract)."""
     prompt, plens, _ = reference
     counts = {}
     for fused in (False, True):

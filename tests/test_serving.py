@@ -1,6 +1,5 @@
 """Serving engine integration: Poisson workload through SpecRouter with
-metric sanity (uses a tiny random pool — fast; trained-pool behavior is
-covered by benchmarks)."""
+metric sanity (uses a tiny random pool — fast)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
